@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import BatchScheduler, Engine
 
 ART = os.path.join(os.path.dirname(__file__), "..", "artifacts")
@@ -265,6 +266,7 @@ def main() -> None:
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--out", default=os.path.join(ART, "BENCH_serving.json"))
     args = ap.parse_args()
+    enable_compile_cache()
 
     rec = measure(args.arch, reduced=not args.full, n_slots=args.slots,
                   max_len=args.max_len, max_new=args.max_new)

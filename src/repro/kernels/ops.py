@@ -1,7 +1,7 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 Handle layout/padding so callers use natural (B, S, H, hd) shapes, and pick
-``interpret=True`` automatically off-TPU so the same call sites work in CPU
+``interpret=True`` on the CPU backend so the same call sites work in CPU
 CI and on real hardware.
 """
 from __future__ import annotations
@@ -18,15 +18,15 @@ from .ssd_scan import ssd_scan as _ssd
 from .rmsnorm import rmsnorm as _rms
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pragma: no cover - device probing
-        return False
-
-
 def default_interpret() -> bool:
-    return not _on_tpu()
+    """Interpret the Pallas kernels on the CPU backend, compile them on the
+    TPU; any other backend is an error, never a silent fallback."""
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(f"no Pallas kernel path for backend {backend!r}")
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",

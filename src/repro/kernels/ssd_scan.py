@@ -25,7 +25,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, fin_ref, h_scr,
-                *, chunk: int, n_chunks: int):
+                *, chunk: int, n_chunks: int, n_heads: int):
     ci = pl.program_id(1)
 
     @pl.when(ci == 0)
@@ -34,18 +34,21 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, fin_ref, h_scr,
 
     x = x_ref[0].astype(jnp.float32)          # (cs, p)
     dt = dt_ref[0].astype(jnp.float32)        # (cs, 1)
-    A = a_ref[0, 0]                           # scalar decay rate (this head)
+    A = a_ref[pl.program_id(0) % n_heads]     # scalar decay rate (SMEM)
     B = b_ref[0].astype(jnp.float32)          # (cs, n)
     C = c_ref[0].astype(jnp.float32)          # (cs, n)
 
     a = dt * A                                # (cs, 1) log-decay per step
     xb = x * dt                               # discretized input
-    cum = jnp.cumsum(a, axis=0)               # (cs, 1)
+    # inclusive prefix sum as a lower-triangular matmul (Mosaic has no
+    # cumsum lowering)
+    tri = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0) >= \
+        jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    cum = jax.lax.dot(tri.astype(jnp.float32), a,
+                      precision=jax.lax.Precision.HIGHEST)   # (cs, 1)
 
     # intra-chunk (quadratic) term
     seg = cum - cum.T                         # (cs, cs): sum_{s+1..l}
-    tri = jax.lax.broadcasted_iota(jnp.int32, seg.shape, 0) >= \
-        jax.lax.broadcasted_iota(jnp.int32, seg.shape, 1)
     L = jnp.where(tri, jnp.exp(seg), 0.0)
     scores = jax.lax.dot_general(C, B, (((1,), (1,)), ((), ())))  # (cs, cs)
     y_d = jax.lax.dot(scores * L, xb)         # (cs, p)
@@ -60,7 +63,12 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, fin_ref, h_scr,
     total = cum[-1:, :]                       # (1,1)
     decay_out = jnp.exp(total - cum)          # (cs, 1)
     S = jax.lax.dot_general(B * decay_out, xb, (((0,), (0,)), ((), ())))
-    h_scr[...] = jnp.exp(total) * h_prev + S  # (n, p)
+    # the chunk's total decay as a (1, p) row: Mosaic cannot broadcast a
+    # (1, 1) value along sublanes and lanes at once
+    p = h_prev.shape[1]
+    total_row = jnp.sum(jnp.broadcast_to(a, (chunk, p)), axis=0,
+                        keepdims=True)
+    h_scr[...] = jnp.exp(total_row) * h_prev + S   # (n, p)
 
     @pl.when(ci == n_chunks - 1)
     def _final():
@@ -78,9 +86,9 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
 
     xf = x.transpose(0, 2, 1, 3).reshape(b * h, s, p)
     dtf = dt.transpose(0, 2, 1).reshape(b * h, s, 1)
-    af = jnp.broadcast_to(A[None, :], (b, h)).reshape(b * h, 1)
 
-    kernel = functools.partial(_ssd_kernel, chunk=chunk, n_chunks=nc)
+    kernel = functools.partial(_ssd_kernel, chunk=chunk, n_chunks=nc,
+                               n_heads=h)
 
     y, fin = pl.pallas_call(
         kernel,
@@ -88,7 +96,9 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
         in_specs=[
             pl.BlockSpec((1, chunk, p), lambda bh, ci: (bh, ci, 0)),
             pl.BlockSpec((1, chunk, 1), lambda bh, ci: (bh, ci, 0)),
-            pl.BlockSpec((1, 1), lambda bh, ci: (bh, 0)),
+            # per-head scalars: the whole (h,) vector sits in SMEM (a
+            # (1, 1) VMEM block would break the (8, 128) tiling rule)
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, chunk, n), lambda bh, ci, H=h: (bh // H, ci, 0)),
             pl.BlockSpec((1, chunk, n), lambda bh, ci, H=h: (bh // H, ci, 0)),
         ],
@@ -102,7 +112,7 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
         ],
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
         interpret=interpret,
-    )(xf, dtf, af, B, C)
+    )(xf, dtf, A.astype(jnp.float32), B, C)
 
     y = y.reshape(b, h, s, p).transpose(0, 2, 1, 3)
     fin = fin.reshape(b, h, n, p).transpose(0, 1, 3, 2)  # (b,h,p,n)

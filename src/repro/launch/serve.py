@@ -11,8 +11,11 @@ from __future__ import annotations
 import argparse
 import time
 
+import jax
+
 from ..configs import ARCHS, get_config
 from ..serving import BatchScheduler, Engine, RunMonitor
+from .compile_cache import enable_compile_cache
 
 
 def main():
@@ -39,6 +42,7 @@ def main():
                     help="wrap the engine's jitted hot paths and print "
                          "per-fn compile counts and call-time stats")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -63,8 +67,10 @@ def main():
     results = sched.run()
     wall = time.time() - t0
     toks = monitor.engine_tokens + len(results)   # + first (prefill) tokens
+    dev = jax.devices()[0]
     print(f"# served {len(results)} requests, {toks} new tokens in "
-          f"{wall:.1f}s ({toks / wall:.1f} tok/s on CPU) — "
+          f"{wall:.1f}s ({toks / wall:.1f} tok/s on {dev.platform} "
+          f"{dev.device_kind}, compiles included) — "
           f"{monitor.engine_steps} decode steps, peak occupancy "
           f"{monitor.engine_peak_live}/{args.slots}, "
           f"{monitor.engine_prefill_tokens} prompt tokens prefilled, "

@@ -42,6 +42,7 @@ from ..traffic import (DEFAULT_MIX, FaultPlan, SLOTarget, Scenario,
                        TrafficDriver, Workload, aggregate_report,
                        register_fault_plan)
 from ..traffic.faults import FaultStats
+from .compile_cache import enable_compile_cache
 
 
 def _mix(args) -> tuple:
@@ -246,6 +247,7 @@ def main() -> None:
     ap.add_argument("--json", action="store_true",
                     help="print the full aggregate as JSON")
     args = ap.parse_args()
+    enable_compile_cache()
 
     mix = _mix(args)
     mults, registry, tenancy = _tenancy(args)

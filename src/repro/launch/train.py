@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from ..configs import ARCHS, get_config
 from ..training.train_loop import train
+from .compile_cache import enable_compile_cache
 
 
 def main():
@@ -31,6 +32,7 @@ def main():
     ap.add_argument("--ckpt", default=None)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

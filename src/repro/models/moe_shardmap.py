@@ -28,7 +28,6 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..configs.base import ModelConfig
@@ -114,7 +113,7 @@ def moe_ffn_shardmap(params: dict, x: jax.Array, cfg: ModelConfig, mesh,
                              model_axis=model_axis, data_axis=data_axes)
     shared_spec = jax.tree_util.tree_map(lambda _: P(None, None),
                                          params.get("shared", {}))
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, None),                       # router replicated
                   P(model_axis, None, None),           # w_gate
@@ -123,7 +122,7 @@ def moe_ffn_shardmap(params: dict, x: jax.Array, cfg: ModelConfig, mesh,
                   shared_spec,
                   P(data_axes, None, None)),           # x
         out_specs=P(data_axes, None, None),
-        check_rep=False)
+        check_vma=False)
     y = fn(params["w_router"], params["experts"]["w_gate"],
            params["experts"]["w_up"], params["experts"]["w_down"],
            params.get("shared", {}), x)

@@ -524,8 +524,13 @@ class BatchScheduler:
                 req.prompt_ids, start, view)
             stats["prefilled"] += len(req.prompt_ids) - start
         else:
-            logits, cache = self.engine.prefill_ids(req.prompt_ids,
-                                                    self.max_len)
+            # the contiguous path's bucketed program (batch padded to
+            # n_slots rows), not a batch-1 prefill: on the TPU the two
+            # programs round differently, and paged == contiguous would
+            # not hold
+            logits, cache = self.engine.prefill_batch_ids(
+                [req.prompt_ids], self.max_len, width=self.n_slots)
+            logits, cache = logits[:1], self._take(cache, 0)
             stats["prefilled"] += len(req.prompt_ids)
         self._pool = self._scatter_prefill(self._pool, cache,
                                            self._table_row(slot),
@@ -843,6 +848,11 @@ class BatchScheduler:
 
     def occupancy(self) -> int:
         return sum(s is not None for s in self.slots)
+
+    def block_until_ready(self) -> None:
+        """Wait until every dispatched step has finished on the device —
+        the end of any wall-clock timing of the scheduler."""
+        jax.block_until_ready(self._pool if self._paged else self._cache)
 
     def drain(self) -> Dict[int, GenerationResult]:
         """Step to completion; returns {rid: GenerationResult}."""

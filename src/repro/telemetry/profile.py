@@ -112,7 +112,9 @@ class JitProfiler:
 
     def wrap(self, name: str, fn: Callable) -> Callable:
         """Profiled drop-in for ``fn``; the original stays reachable as
-        ``wrapper.__wrapped__``."""
+        ``wrapper.__wrapped__``.  A failing ``block_until_ready``
+        propagates: it is the device's error, not the profiler's."""
+        import jax   # lazy: the telemetry package stays importable jax-free
         prof = self.profile(name)
         cache_size = getattr(fn, "_cache_size", None)
 
@@ -121,11 +123,7 @@ class JitProfiler:
             before = cache_size() if callable(cache_size) else None
             t0 = self._clock()
             out = fn(*args, **kwargs)
-            try:
-                import jax
-                jax.block_until_ready(out)
-            except Exception:
-                pass
+            jax.block_until_ready(out)
             dt = self._clock() - t0
             with self._lock:
                 prof.calls += 1

@@ -157,3 +157,15 @@ def test_rmsnorm(shape, dtype):
     ref = rmsnorm_ref(x, scale)
     err = jnp.max(jnp.abs(out.astype(jnp.float32) - ref.astype(jnp.float32)))
     assert float(err) < TOL[dtype]
+
+
+def test_default_interpret_follows_backend(monkeypatch):
+    """Interpret on the CPU backend, compile on the TPU, and refuse any
+    other backend instead of quietly interpreting there."""
+    from repro.kernels.ops import default_interpret
+    assert default_interpret() is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert default_interpret() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        default_interpret()

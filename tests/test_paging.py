@@ -206,6 +206,30 @@ def test_paged_prefix_hits_and_gauges():
     assert snap["engine_blocks_in_use"] >= 0
 
 
+def test_paged_miss_admission_uses_bucketed_program(monkeypatch):
+    """A prefix-cache miss prefills through the contiguous path's
+    batch-``n_slots`` program: on the TPU a batch-1 prefill rounds
+    differently, and paged == contiguous would break there."""
+    eng = get_engine("gqa", 0.0)
+    widths = []
+    batch = eng.prefill_batch_ids
+
+    def spy(ids_list, cache_len, width=None):
+        widths.append(width)
+        return batch(ids_list, cache_len, width=width)
+
+    def batch_1(*args, **kwargs):
+        raise AssertionError("paged admission ran a batch-1 prefill")
+
+    monkeypatch.setattr(eng, "prefill_batch_ids", spy)
+    monkeypatch.setattr(eng, "prefill_ids", batch_1)
+    sched = BatchScheduler(eng, n_slots=3, max_len=MAX_LEN, paged_kv=True,
+                           block_size=BLOCK)
+    sched.submit(prompt_ids=list(range(1, 12)), max_new=2)
+    sched.drain()
+    assert widths == [3]
+
+
 def test_contiguous_emits_zero_paging_gauges():
     """With paging off the new gauges stay at their defaults — the
     wire payload is exactly the pre-paging one."""

@@ -380,6 +380,21 @@ def test_profiler_counts_calls_and_compiles():
     assert any("f" in row for row in prof.table())
 
 
+def test_profiler_propagates_device_errors():
+    """A failing ``block_until_ready`` is the device's error: the wrapper
+    raises it instead of timing the enqueue and carrying on."""
+    class _Failing:
+        def block_until_ready(self):
+            raise RuntimeError("device lost")
+
+    prof = JitProfiler()
+    g = prof.wrap("f", lambda: _Failing())
+    with pytest.raises(RuntimeError, match="device lost"):
+        g()
+    h = prof.wrap("g", lambda: {"n": 3, "name": "x"})   # non-arrays pass
+    assert h() == {"n": 3, "name": "x"}
+
+
 def test_profiler_keeps_private_registry_by_default():
     """Wall times are nondeterministic, so they must not leak into a
     bridge registry that byte-identical-replay tests compare."""
