@@ -222,7 +222,12 @@ class EngineStepped(RunEvent):
     deserialize — and the contiguous-cache scheduler emits exactly the
     pre-paging payload): ``blocks_in_use`` is the block allocator's
     occupancy after the step, ``prefix_hits`` how many admissions this
-    step reused cached prefix blocks."""
+    step reused cached prefix blocks.
+
+    ``prefill_rows`` (default 0, so older payloads still deserialize)
+    counts the rows the step's prefill programs computed, padding
+    included (``Engine.prefill_rows``); ``prefilled`` counts only the
+    real tokens among them."""
     live: int
     queued: int
     generated: int
@@ -230,6 +235,7 @@ class EngineStepped(RunEvent):
     preempted: int = 0
     blocks_in_use: int = 0
     prefix_hits: int = 0
+    prefill_rows: int = 0
 
 
 # ---------------------------------------------------------------------------

@@ -38,6 +38,15 @@ def prefill_bucket(n: int, floor: int = 8) -> int:
     return max(floor, 1 << max(n - 1, 0).bit_length())
 
 
+def _named(fn, **bound):
+    """``functools.partial(fn, **bound)`` under ``fn``'s own name: jit
+    names its program (and the profiler that program's device ops) after
+    ``__name__``, which a bare partial lacks (``jit__unknown``)."""
+    p = functools.partial(fn, **bound)
+    p.__name__ = p.__qualname__ = fn.__name__
+    return p
+
+
 def cache_leaf_name(path) -> Optional[str]:
     """Name of a cache leaf ("k"/"v"/"ckv"/"kpe"/"conv"/"ssd") from its
     tree path — shared by seq-axis padding here and the slot-batch row
@@ -356,6 +365,15 @@ class Engine:
     serial ``generate_ids`` path and the batch scheduler's admission —
     sharing the recipe is what keeps chunked admission bit-identical to
     serial generation.
+
+    ``prefill_rows`` counts the rows the prefill programs have computed
+    since construction, padding included: ``width x bucket`` for a
+    bucketed batch, the bucket of a whole prompt or a reused-prefix
+    suffix, a whole chunk per chunk, the prompt length on the
+    exact-length recipe, and one row per replayed decode token.  Against
+    the real prompt tokens it gives the share of prefill compute spent
+    on padding (``BatchScheduler`` reports each step's delta on
+    ``EngineStepped.prefill_rows``).
     """
 
     def __init__(self, cfg: ModelConfig, params=None, seed: int = 0,
@@ -369,24 +387,30 @@ class Engine:
         self.temperature = temperature
         self.top_p = top_p
         self.prefill_chunk = int(prefill_chunk)
-        self._prefill = jax.jit(functools.partial(prefill, cfg=cfg))
+        self.prefill_rows = 0
+        self._rows_lock = threading.Lock()
+        self._prefill = jax.jit(_named(prefill, cfg=cfg))
         # fixed-shape prefill pair (bucketed whole prompts / chunks):
         # one trace per (batch, bucket, cache_len) — lengths and offset
         # are traced values, so every prompt length in a bucket shares it
         self._prefill_fixed = jax.jit(
-            functools.partial(prefill_fresh, cfg=cfg),
+            _named(prefill_fresh, cfg=cfg),
             static_argnames=("cache_len",))
         self._prefill_extend = jax.jit(
-            functools.partial(prefill_attend, cfg=cfg),
+            _named(prefill_attend, cfg=cfg),
             donate_argnames=("cache",))
         # cache is donated: the decode loop threads it linearly, and the
         # in-place update keeps the per-step cost flat in cache size
         # (without donation XLA copies the whole slot batch every step)
-        self._decode = jax.jit(functools.partial(decode_step, cfg=cfg),
+        self._decode = jax.jit(_named(decode_step, cfg=cfg),
                                donate_argnames=("cache",))
         self._base_key = jax.random.key(seed + 1)
         self._sampler = None
         self._sampler_knobs = None
+
+    def _count_rows(self, n: int) -> None:
+        with self._rows_lock:
+            self.prefill_rows += n
 
     @property
     def supports_fixed_shape_prefill(self) -> bool:
@@ -468,6 +492,7 @@ class Engine:
         tokens = jnp.asarray([list(ids) + [0] * (bucket - len(ids))],
                              jnp.int32)
         lengths = jnp.asarray([len(ids)], jnp.int32)
+        self._count_rows(bucket)
         return self._prefill_fixed(self.params, tokens=tokens,
                                    lengths=lengths,
                                    cache_len=int(cache_len))
@@ -485,6 +510,7 @@ class Engine:
         if cfg.frontend:
             fe = jnp.zeros((1, cfg.frontend_positions, cfg.d_model),
                            self.params["embed"].dtype)
+        self._count_rows(len(ids))
         logits, cache = self._prefill(self.params, tokens=prompt,
                                       frontend_embeds=fe)
         cache = pad_cache_to(cfg, cache, cache_len +
@@ -510,6 +536,7 @@ class Engine:
         tokens = jnp.asarray([r + [0] * (bucket - len(r)) for r in rows],
                              jnp.int32)
         lengths = jnp.asarray([len(r) for r in rows], jnp.int32)
+        self._count_rows(width * bucket)
         return self._prefill_fixed(self.params, tokens=tokens,
                                    lengths=lengths,
                                    cache_len=int(cache_len))
@@ -542,6 +569,7 @@ class Engine:
         bucket = prefill_bucket(len(suffix))
         tokens = jnp.asarray([suffix + [0] * (bucket - len(suffix))],
                              jnp.int32)
+        self._count_rows(bucket)
         return self._prefill_extend(self.params, cache=cache, tokens=tokens,
                                     off=jnp.int32(start),
                                     lengths=jnp.asarray([len(suffix)],
@@ -563,6 +591,7 @@ class Engine:
         _, cache = self.prefill_ids(ids, cache_len)
         offset = self.cfg.frontend_positions if self.cfg.frontend else 0
         base = offset + len(ids)
+        self._count_rows(len(kept) - 1)
         for i, tok in enumerate(kept[:-1]):
             _, cache = self._decode(self.params, cache=cache,
                                     token=jnp.asarray([[tok]], jnp.int32),
@@ -662,6 +691,7 @@ class PrefillJob:
         chunk = self.ids[self.off:self.off + self.chunk]
         valid = len(chunk)
         tokens = jnp.asarray([chunk + [0] * (self.chunk - valid)], jnp.int32)
+        self.engine._count_rows(self.chunk)
         self.logits, self.cache = self.engine._prefill_extend(
             self.engine.params, cache=self.cache, tokens=tokens,
             off=jnp.int32(self.off),

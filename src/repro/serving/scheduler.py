@@ -63,11 +63,17 @@ therefore share the decode batch instead of serializing on the engine.
 
 Observability: each step emits a serving-side
 :class:`repro.core.events.EngineStepped` run event (occupancy, queue
-depth, tokens decoded, prompt tokens prefilled, slots preempted) to
-subscribers — ``RunMonitor`` consumes it live.
+depth, tokens decoded, prompt tokens prefilled and prefill rows
+computed, slots preempted) to subscribers — ``RunMonitor`` consumes it
+live.  Each step, its phases and each admission are also marked with
+``jax.profiler.TraceAnnotation`` spans (``BatchScheduler.step`` and its
+children, ``docs/OBSERVABILITY.md``), which land on the device trace's
+clock when a profiler runs and cost about a microsecond each when none
+does.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import heapq
 import threading
@@ -76,6 +82,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from ..core.events import EngineStepped
 from ..models.model import (copy_block, gather_cache, init_cache,
@@ -96,9 +103,16 @@ class Request:
     ``tenant``: the billing principal (multi-tenant serving) — under
     fair-share admission requests queue per tenant and slots are granted
     in deficit-round-robin order across tenants.
-    ``t_submit`` / ``t_first_token``: wall-clock stamps (``time.perf_counter``)
-    used by ``benchmarks/serving.py`` for admission-latency (TTFT)
-    percentiles.
+    ``t_submit`` / ``t_admit`` / ``t_first_token``: ``time.perf_counter``
+    stamps of the enqueue, of the first time the request left the queue
+    for a slot or a chunk job, and of its first token; ``t_admit -
+    t_submit`` is its queue wait and ``t_first_token - t_admit`` its
+    prefill.  ``admit_step`` is the scheduler's step count at that
+    admission (-1 until then) and ``reused`` the prompt rows that
+    shared prefix-cache blocks served there (0 off the paged path).  A
+    resume after preemption keeps all three.  ``benchmarks/serving.py``
+    reads the TTFT stamps; ``bench/tools/program_metrics.py`` all of
+    them.
     """
     rid: int
     prompt_ids: List[int]
@@ -110,7 +124,10 @@ class Request:
     seq: int = 0
     preemptions: int = 0
     t_submit: float = 0.0
+    t_admit: float = 0.0
     t_first_token: float = 0.0
+    admit_step: int = -1
+    reused: int = 0
 
     def to_result(self, tokenizer) -> GenerationResult:
         return GenerationResult(tokenizer.decode(self.out_ids),
@@ -312,10 +329,11 @@ class BatchScheduler:
                else self.engine.tokenizer.encode(prompt))
         max_new = max(1, min(max_new, self.max_len - 1))
         ids = ids[-(self.max_len - max_new):]
-        with self._qlock:
+        with TraceAnnotation("BatchScheduler.submit") as span, self._qlock:
             req = Request(self._next_rid, ids, max_new, priority=priority,
                           tenant=tenant, seq=self._seq,
                           t_submit=time.perf_counter())
+            span.set_metadata(rid=req.rid)
             self._next_rid += 1
             self._seq += 1
             self.requests[req.rid] = req
@@ -358,6 +376,29 @@ class BatchScheduler:
     def _free_slots(self) -> List[int]:
         return [i for i in range(self.n_slots)
                 if self.slots[i] is None and i not in self._reserved]
+
+    def _admitted(self, req: Request, reused: int,
+                  t: Optional[float] = None) -> None:
+        """Stamp a request's first admission (a resume keeps it)."""
+        if req.admit_step < 0:
+            req.t_admit = time.perf_counter() if t is None else t
+            req.admit_step = self._steps
+            req.reused = reused
+
+    @contextlib.contextmanager
+    def _admission(self, name: str, req: Request, reused: int):
+        """One admission's span: the request, its reused prefix rows, and
+        the prefill rows it computed."""
+        rows0 = self.engine.prefill_rows
+        with TraceAnnotation(name, rid=req.rid, reused=reused) as span:
+            yield
+            span.set_metadata(rows=self.engine.prefill_rows - rows0)
+
+    def _sample_first(self, req: Request, logits) -> int:
+        """Sample and read back a request's first token from its prefill
+        logits (row 0)."""
+        with TraceAnnotation("BatchScheduler.readback", rid=req.rid):
+            return int(self.engine.sample(logits, [req.rid], [0])[0])
 
     def _first_token(self, req: Request, tok: int,
                      finished: List[Request]) -> bool:
@@ -493,12 +534,15 @@ class BatchScheduler:
             return self._paged_prefill_into(slot, req, finished, stats)
         prefill = (self.engine.prefill_ids if self.batched_prefill
                    else self.engine.prefill_ids_exact)
-        logits, cache = prefill(req.prompt_ids, self.max_len)
-        stats["prefilled"] += len(req.prompt_ids)
-        tok = int(self.engine.sample(logits, [req.rid], [0])[0])
-        if self._first_token(req, tok, finished):
-            self._cache = self._insert(self._cache, cache, slot)
-            self._occupy(slot, req, self._offset + len(req.prompt_ids), tok)
+        self._admitted(req, 0)
+        with self._admission("BatchScheduler.admit_bucket", req, 0):
+            logits, cache = prefill(req.prompt_ids, self.max_len)
+            stats["prefilled"] += len(req.prompt_ids)
+            tok = self._sample_first(req, logits)
+            if self._first_token(req, tok, finished):
+                self._cache = self._insert(self._cache, cache, slot)
+                self._occupy(slot, req, self._offset + len(req.prompt_ids),
+                             tok)
         return True
 
     def _paged_prefill_into(self, slot: int, req: Request,
@@ -514,34 +558,38 @@ class BatchScheduler:
             self._push(req)
             return False
         start, blocks = got
+        self._admitted(req, start)
         self._blocks[slot] = blocks
         self._tables_dirty = True
-        if start:
-            # shared blocks already hold rows 0..start-1: gather this
-            # slot's view and prefill only the divergent suffix
-            view = self._gather(self._pool, self._table_row(slot)[None])
-            logits, cache = self.engine.prefill_continue(
-                req.prompt_ids, start, view)
-            stats["prefilled"] += len(req.prompt_ids) - start
-        else:
-            # the contiguous path's bucketed program (batch padded to
-            # n_slots rows), not a batch-1 prefill: on the TPU the two
-            # programs round differently, and paged == contiguous would
-            # not hold
-            logits, cache = self.engine.prefill_batch_ids(
-                [req.prompt_ids], self.max_len, width=self.n_slots)
-            logits, cache = logits[:1], self._take(cache, 0)
-            stats["prefilled"] += len(req.prompt_ids)
-        self._pool = self._scatter_prefill(self._pool, cache,
-                                           self._table_row(slot),
-                                           jnp.int32(start))
-        if self._prefix is not None:
-            self._prefix.insert(req.prompt_ids, blocks)
-        tok = int(self.engine.sample(logits, [req.rid], [0])[0])
-        if self._first_token(req, tok, finished):
-            self._occupy(slot, req, self._offset + len(req.prompt_ids), tok)
-        else:
-            self._free_slot_blocks(slot)
+        with self._admission("BatchScheduler.admit_prefix_hit" if start
+                             else "BatchScheduler.admit_bucket", req, start):
+            if start:
+                # shared blocks already hold rows 0..start-1: gather this
+                # slot's view and prefill only the divergent suffix
+                view = self._gather(self._pool, self._table_row(slot)[None])
+                logits, cache = self.engine.prefill_continue(
+                    req.prompt_ids, start, view)
+                stats["prefilled"] += len(req.prompt_ids) - start
+            else:
+                # the contiguous path's bucketed program (batch padded to
+                # n_slots rows), not a batch-1 prefill: on the TPU the two
+                # programs round differently, and paged == contiguous
+                # would not hold
+                logits, cache = self.engine.prefill_batch_ids(
+                    [req.prompt_ids], self.max_len, width=self.n_slots)
+                logits, cache = logits[:1], self._take(cache, 0)
+                stats["prefilled"] += len(req.prompt_ids)
+            self._pool = self._scatter_prefill(self._pool, cache,
+                                               self._table_row(slot),
+                                               jnp.int32(start))
+            if self._prefix is not None:
+                self._prefix.insert(req.prompt_ids, blocks)
+            tok = self._sample_first(req, logits)
+            if self._first_token(req, tok, finished):
+                self._occupy(slot, req, self._offset + len(req.prompt_ids),
+                             tok)
+            else:
+                self._free_slot_blocks(slot)
         return True
 
     def _admit_bucket(self, group: List[Request], free: List[int],
@@ -551,41 +599,47 @@ class BatchScheduler:
         same trace).  In paged mode (prefix cache off — hit-aware
         admission goes per-request through ``_paged_prefill_into``) each
         row scatters into its slot's freshly allocated blocks.  False =
-        the paged pool ran out mid-group (unplaced members requeued)."""
-        logits, cache = self.engine.prefill_batch_ids(
-            [r.prompt_ids for r in group], self.max_len, width=self.n_slots)
-        slot_iter = iter(free)
-        exhausted = False
-        for j, req in enumerate(group):
-            if exhausted:
-                self._push(req)
-                continue
-            blocks: List[int] = []
-            if self._paged:
-                got = self._paged_admit_blocks(req.prompt_ids,
-                                               len(req.prompt_ids), stats)
-                if got is None:
-                    exhausted = True
+        the paged pool ran out mid-group (unplaced members requeued).
+        The group's span carries its first request's id."""
+        t = time.perf_counter()
+        with self._admission("BatchScheduler.admit_bucket", group[0], 0):
+            logits, cache = self.engine.prefill_batch_ids(
+                [r.prompt_ids for r in group], self.max_len,
+                width=self.n_slots)
+            slot_iter = iter(free)
+            exhausted = False
+            for j, req in enumerate(group):
+                if exhausted:
                     self._push(req)
                     continue
-                _, blocks = got
-            stats["prefilled"] += len(req.prompt_ids)
-            tok = int(self.engine.sample(logits[j:j + 1], [req.rid], [0])[0])
-            if self._first_token(req, tok, finished):
-                slot = next(slot_iter)
-                row = self._take(cache, j)
+                blocks: List[int] = []
                 if self._paged:
-                    self._blocks[slot] = blocks
-                    self._tables_dirty = True
-                    self._pool = self._scatter_prefill(
-                        self._pool, row, self._table_row(slot), jnp.int32(0))
-                else:
-                    self._cache = self._insert(self._cache, row, slot)
-                self._occupy(req=req, slot=slot, tok=tok,
-                             pos=self._offset + len(req.prompt_ids))
-            elif self._paged:
-                for bid in blocks:
-                    self._alloc.decref(bid)
+                    got = self._paged_admit_blocks(req.prompt_ids,
+                                                   len(req.prompt_ids), stats)
+                    if got is None:
+                        exhausted = True
+                        self._push(req)
+                        continue
+                    _, blocks = got
+                self._admitted(req, 0, t)
+                stats["prefilled"] += len(req.prompt_ids)
+                tok = self._sample_first(req, logits[j:j + 1])
+                if self._first_token(req, tok, finished):
+                    slot = next(slot_iter)
+                    row = self._take(cache, j)
+                    if self._paged:
+                        self._blocks[slot] = blocks
+                        self._tables_dirty = True
+                        self._pool = self._scatter_prefill(
+                            self._pool, row, self._table_row(slot),
+                            jnp.int32(0))
+                    else:
+                        self._cache = self._insert(self._cache, row, slot)
+                    self._occupy(req=req, slot=slot, tok=tok,
+                                 pos=self._offset + len(req.prompt_ids))
+                elif self._paged:
+                    for bid in blocks:
+                        self._alloc.decref(bid)
         return not exhausted
 
     def _resume_into(self, slot: int, req: Request,
@@ -605,20 +659,23 @@ class BatchScheduler:
             start, blocks = got
             self._blocks[slot] = blocks
             self._tables_dirty = True
+            with self._admission("BatchScheduler.admit_resume", req, start):
+                cache, pos, tok = self.engine.replay_ids(
+                    req.prompt_ids, req.out_ids, self.max_len)
+                stats["prefilled"] += (len(req.prompt_ids)
+                                       + len(req.out_ids) - 1)
+                self._pool = self._scatter_prefill(self._pool, cache,
+                                                   self._table_row(slot),
+                                                   jnp.int32(start))
+                if self._prefix is not None:
+                    self._prefix.insert(req.prompt_ids, blocks)
+            self._occupy(slot, req, pos, tok)
+            return True
+        with self._admission("BatchScheduler.admit_resume", req, 0):
             cache, pos, tok = self.engine.replay_ids(
                 req.prompt_ids, req.out_ids, self.max_len)
             stats["prefilled"] += len(req.prompt_ids) + len(req.out_ids) - 1
-            self._pool = self._scatter_prefill(self._pool, cache,
-                                               self._table_row(slot),
-                                               jnp.int32(start))
-            if self._prefix is not None:
-                self._prefix.insert(req.prompt_ids, blocks)
-            self._occupy(slot, req, pos, tok)
-            return True
-        cache, pos, tok = self.engine.replay_ids(
-            req.prompt_ids, req.out_ids, self.max_len)
-        stats["prefilled"] += len(req.prompt_ids) + len(req.out_ids) - 1
-        self._cache = self._insert(self._cache, cache, slot)
+            self._cache = self._insert(self._cache, cache, slot)
         self._occupy(slot, req, pos, tok)
         return True
 
@@ -628,29 +685,11 @@ class BatchScheduler:
         first."""
         if self._chunk_job is not None:
             job, req, slot = self._chunk_job
-            stats["prefilled"] += job.step()
-            if job.done:
-                self._chunk_job = None
-                self._reserved.discard(slot)
-                tok = int(self.engine.sample(job.logits, [req.rid], [0])[0])
-                if self._paged:
-                    # scatter skips the job's reused-prefix rows (they
-                    # live in shared blocks the job never rewrote)
-                    self._pool = self._scatter_prefill(
-                        self._pool, job.cache, self._table_row(slot),
-                        jnp.int32(job.start))
-                    if self._prefix is not None:
-                        self._prefix.insert(req.prompt_ids,
-                                            self._blocks[slot])
-                    if self._first_token(req, tok, finished):
-                        self._occupy(slot, req,
-                                     self._offset + len(req.prompt_ids), tok)
-                    else:
-                        self._free_slot_blocks(slot)
-                elif self._first_token(req, tok, finished):
-                    self._cache = self._insert(self._cache, job.cache, slot)
-                    self._occupy(slot, req,
-                                 self._offset + len(req.prompt_ids), tok)
+            with self._admission("BatchScheduler.admit_chunk", req,
+                                 job.start):
+                stats["prefilled"] += job.step()
+                if job.done:
+                    self._finish_chunk_job(job, req, slot, finished)
         while True:
             free = self._free_slots()
             if not free:
@@ -669,6 +708,7 @@ class BatchScheduler:
                     self._push(req)
                     return
                 slot = free[0]
+                start = 0
                 if self._paged:
                     got = self._paged_admit_blocks(
                         req.prompt_ids, len(req.prompt_ids), stats)
@@ -678,6 +718,9 @@ class BatchScheduler:
                     start, blocks = got
                     self._blocks[slot] = blocks
                     self._tables_dirty = True
+                self._admitted(req, start)
+                with self._admission("BatchScheduler.admit_chunk", req,
+                                     start):
                     if start:
                         # hot prefix: the chunk job starts at the first
                         # divergent row against the gathered slot view
@@ -689,11 +732,8 @@ class BatchScheduler:
                     else:
                         job = self.engine.prefill_job(req.prompt_ids,
                                                       self.max_len)
-                else:
-                    job = self.engine.prefill_job(req.prompt_ids,
-                                                  self.max_len)
-                self._reserved.add(slot)
-                stats["prefilled"] += job.step()   # first chunk this step
+                    self._reserved.add(slot)
+                    stats["prefilled"] += job.step()   # first chunk
                 self._chunk_job = (job, req, slot)
                 continue
             if (self.batched_prefill
@@ -711,6 +751,30 @@ class BatchScheduler:
             else:
                 if not self._prefill_into(free[0], req, finished, stats):
                     return
+
+    def _finish_chunk_job(self, job: PrefillJob, req: Request, slot: int,
+                          finished: List[Request]) -> None:
+        """A chunk job's prompt is all in: sample its first token and move
+        its cache into the slot it reserved."""
+        self._chunk_job = None
+        self._reserved.discard(slot)
+        tok = self._sample_first(req, job.logits)
+        if self._paged:
+            # scatter skips the job's reused-prefix rows (they live in
+            # shared blocks the job never rewrote)
+            self._pool = self._scatter_prefill(
+                self._pool, job.cache, self._table_row(slot),
+                jnp.int32(job.start))
+            if self._prefix is not None:
+                self._prefix.insert(req.prompt_ids, self._blocks[slot])
+            if self._first_token(req, tok, finished):
+                self._occupy(slot, req, self._offset + len(req.prompt_ids),
+                             tok)
+            else:
+                self._free_slot_blocks(slot)
+        elif self._first_token(req, tok, finished):
+            self._cache = self._insert(self._cache, job.cache, slot)
+            self._occupy(slot, req, self._offset + len(req.prompt_ids), tok)
 
     def _pop_matching(self, bucket: int,
                       leader: Optional[Request] = None) -> Optional[Request]:
@@ -769,54 +833,80 @@ class BatchScheduler:
         live slot, admit into free slots (chunked / bucketed / resume),
         then advance ALL live slots one token with a single jitted decode
         over the slot batch. Returns the requests that finished this
-        step."""
+        step.
+
+        Traced as ``BatchScheduler.step`` with the phases as child spans:
+        ``preempt``, ``admit`` (one child per admission), ``ensure_blocks``
+        (paged), ``decode_inputs`` (host arrays), ``decode_dispatch``
+        (gather, decode, scatter, sample), ``readback`` (the sampled
+        tokens' pull to the host) and ``bookkeeping``."""
+        with TraceAnnotation("BatchScheduler.step", step=self._steps,
+                             live=self.occupancy(),
+                             queued=self.queue_depth()):
+            return self._step()
+
+    def _step(self) -> List[Request]:
         finished: List[Request] = []
         stats = {"prefilled": 0, "preempted": 0, "prefix_hits": 0}
-        self._preempt(stats)
-        self._admit(finished, stats)
+        rows0 = self.engine.prefill_rows
+        with TraceAnnotation("BatchScheduler.preempt"):
+            self._preempt(stats)
+        with TraceAnnotation("BatchScheduler.admit"):
+            self._admit(finished, stats)
         live = [i for i in range(self.n_slots) if self.slots[i] is not None]
         if self._paged:
             # grow each live slot's table to cover its write position;
             # a slot that cannot get a block self-preempts (resume later
             # replays it bit-identically, so nothing is lost)
-            for i in list(live):
-                if not self._ensure_block(i):
-                    req = self.slots[i]
-                    self.slots[i] = None
-                    self._free_slot_blocks(i)
-                    req.preemptions += 1
-                    stats["preempted"] += 1
-                    self._push(req)
-                    live.remove(i)
+            with TraceAnnotation("BatchScheduler.ensure_blocks"):
+                for i in list(live):
+                    if not self._ensure_block(i):
+                        req = self.slots[i]
+                        self.slots[i] = None
+                        self._free_slot_blocks(i)
+                        req.preemptions += 1
+                        stats["preempted"] += 1
+                        self._push(req)
+                        live.remove(i)
+        toks: List[int] = []
         if live:
-            tokens = jnp.asarray([[t] for t in self._tok], jnp.int32)
-            pos = jnp.asarray(self._pos, jnp.int32)
-            if self._paged:
-                # gather pool -> dense view, decode with the SAME jitted
-                # executable as the contiguous path (bit parity), scatter
-                # the freshly written rows back into the pool.  Only LIVE
-                # slots write back: a dead or chunk-reserved slot decodes
-                # junk at a stale position (exactly like the contiguous
-                # path), and its table may already hold SHARED prefix
-                # blocks — its row is redirected to the trash block.
-                tables = self._tables_device()
-                live_rows = jnp.asarray(
-                    [self.slots[i] is not None for i in range(self.n_slots)])
-                wtables = jnp.where(live_rows[:, None], tables, self._trash)
-                view = self._gather(self._pool, tables)
-                logits, view = self.engine._decode(
-                    self.engine.params, cache=view, token=tokens, pos=pos)
-                self._pool = self._scatter_rows(self._pool, view, wtables,
-                                                pos)
-            else:
-                logits, self._cache = self.engine._decode(
-                    self.engine.params, cache=self._cache, token=tokens,
-                    pos=pos)
-            rids = [r.rid if (r := self.slots[i]) is not None else 0
-                    for i in range(self.n_slots)]
-            steps = [len(r.out_ids) if (r := self.slots[i]) is not None else 0
-                     for i in range(self.n_slots)]
-            toks = [int(t) for t in self.engine.sample(logits, rids, steps)]
+            with TraceAnnotation("BatchScheduler.decode_inputs"):
+                tokens = jnp.asarray([[t] for t in self._tok], jnp.int32)
+                pos = jnp.asarray(self._pos, jnp.int32)
+                rids = [r.rid if (r := self.slots[i]) is not None else 0
+                        for i in range(self.n_slots)]
+                steps = [len(r.out_ids) if (r := self.slots[i]) is not None
+                         else 0 for i in range(self.n_slots)]
+                if self._paged:
+                    # only LIVE slots write back: a dead or chunk-reserved
+                    # slot decodes junk at a stale position (exactly like
+                    # the contiguous path), and its table may already hold
+                    # SHARED prefix blocks — its row goes to the trash block
+                    tables = self._tables_device()
+                    live_rows = jnp.asarray(
+                        [self.slots[i] is not None
+                         for i in range(self.n_slots)])
+                    wtables = jnp.where(live_rows[:, None], tables,
+                                        self._trash)
+            with TraceAnnotation("BatchScheduler.decode_dispatch"):
+                if self._paged:
+                    # gather pool -> dense view, decode with the SAME
+                    # jitted executable as the contiguous path (bit
+                    # parity), scatter the fresh rows back into the pool
+                    view = self._gather(self._pool, tables)
+                    logits, view = self.engine._decode(
+                        self.engine.params, cache=view, token=tokens,
+                        pos=pos)
+                    self._pool = self._scatter_rows(self._pool, view,
+                                                    wtables, pos)
+                else:
+                    logits, self._cache = self.engine._decode(
+                        self.engine.params, cache=self._cache, token=tokens,
+                        pos=pos)
+                sampled = self.engine.sample(logits, rids, steps)
+            with TraceAnnotation("BatchScheduler.readback"):
+                toks = [int(t) for t in sampled]
+        with TraceAnnotation("BatchScheduler.bookkeeping"):
             eos = self.engine.tokenizer.eos
             for i in live:
                 req = self.slots[i]
@@ -829,15 +919,14 @@ class BatchScheduler:
                     self.slots[i] = None   # slot freed -> next admission
                     if self._paged:
                         self._free_slot_blocks(i)
-        self._steps += 1
-        self._emit(EngineStepped(t=float(self._steps), live=len(live),
-                                 queued=self.queue_depth(),
-                                 generated=len(live),
-                                 prefilled=stats["prefilled"],
-                                 preempted=stats["preempted"],
-                                 blocks_in_use=(self._alloc.in_use
-                                                if self._paged else 0),
-                                 prefix_hits=stats["prefix_hits"]))
+            self._steps += 1
+            self._emit(EngineStepped(
+                t=float(self._steps), live=len(live),
+                queued=self.queue_depth(), generated=len(live),
+                prefilled=stats["prefilled"], preempted=stats["preempted"],
+                blocks_in_use=self._alloc.in_use if self._paged else 0,
+                prefix_hits=stats["prefix_hits"],
+                prefill_rows=self.engine.prefill_rows - rows0))
         return finished
 
     # -- draining -----------------------------------------------------------

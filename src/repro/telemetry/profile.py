@@ -17,9 +17,7 @@ replays while profile stats ride in a separate export/section.
 
 ``wrap_engine`` hooks the serving engine's jitted members in place
 (``decode_step``, the fixed-shape prefill behind ``prefill_batch_ids``,
-the extend/chunk path, exact prefill); ``wrap_kernel_ops`` rebinds the
-Pallas kernel wrappers (``paged_decode_attention_op`` et al.) at module
-level and returns a restore handle.
+the extend/chunk path, exact prefill).
 """
 from __future__ import annotations
 
@@ -171,33 +169,6 @@ class JitProfiler:
             if fn is None or getattr(fn, "_jit_profiled", False):
                 continue
             setattr(engine, attr, self.wrap(name, fn))
-
-    KERNEL_OPS = ("paged_decode_attention_op", "decode_attention_op",
-                  "flash_attention_op")
-
-    def wrap_kernel_ops(self) -> Callable[[], None]:
-        """Rebind the Pallas kernel wrappers at module level; returns a
-        zero-arg restore function (tests unhook in a finally)."""
-        from .. import kernels
-        from ..kernels import ops
-        originals: List = []
-        for name in self.KERNEL_OPS:
-            fn = getattr(ops, name, None)
-            if fn is None or getattr(fn, "_jit_profiled", False):
-                continue
-            wrapped = self.wrap(name, fn)
-            originals.append((name, fn))
-            setattr(ops, name, wrapped)
-            if hasattr(kernels, name):
-                setattr(kernels, name, wrapped)
-
-        def restore() -> None:
-            for name, fn in originals:
-                setattr(ops, name, fn)
-                if hasattr(kernels, name):
-                    setattr(kernels, name, fn)
-
-        return restore
 
     # -- summaries -----------------------------------------------------------
     def stats(self) -> Dict[str, Dict[str, Any]]:

@@ -47,7 +47,8 @@ SAMPLES = [
     PlanFallback(t=6.4, key="ab12" * 16, reason="node-failed:fetch",
                  stage=1),
     EngineStepped(t=7.0, live=3, queued=2, generated=3, prefilled=64,
-                  preempted=1, blocks_in_use=12, prefix_hits=2),
+                  preempted=1, blocks_in_use=12, prefix_hits=2,
+                  prefill_rows=96),
     RunDegraded(t=0.0, tenant="acme", reason="soft budget exhaustion",
                 from_pattern="agentx", to_pattern="agentx-compiled",
                 from_deployment="faas", to_deployment="local"),
@@ -110,6 +111,17 @@ def test_pre_paging_enginestepped_payload_defaults():
            "generated": 2, "prefilled": 16, "preempted": 0}
     ev = from_wire(old)
     assert ev.blocks_in_use == 0 and ev.prefix_hits == 0
+
+
+def test_pre_prefill_rows_enginestepped_payload_defaults():
+    """An EngineStepped payload from before the prefill-row counter
+    still deserializes, with no rows counted."""
+    old = {"type": "EngineStepped", "v": 2, "t": 1.0, "live": 2,
+           "queued": 0, "generated": 2, "prefilled": 16, "preempted": 0,
+           "blocks_in_use": 3, "prefix_hits": 1}
+    ev = from_wire(old)
+    assert ev.prefill_rows == 0 and ev.prefilled == 16
+    assert to_wire(ev)["prefill_rows"] == 0
 
 
 def test_pre_plan_toolevent_payload_defaults():

@@ -405,25 +405,6 @@ def test_profiler_keeps_private_registry_by_default():
     assert shared.registry is bridge.registry
 
 
-def test_wrap_kernel_ops_rebinds_and_restores():
-    from repro import kernels
-    from repro.kernels import ops
-    prof = JitProfiler()
-    originals = {n: getattr(ops, n) for n in prof.KERNEL_OPS
-                 if hasattr(ops, n)}
-    assert originals, "expected kernel ops to wrap"
-    restore = prof.wrap_kernel_ops()
-    try:
-        for n in originals:
-            assert getattr(ops, n).__wrapped__ is originals[n]
-            if hasattr(kernels, n):
-                assert getattr(kernels, n).__wrapped__ is originals[n]
-    finally:
-        restore()
-    for n, fn in originals.items():
-        assert getattr(ops, n) is fn
-
-
 # ---------------------------------------------------------------------------
 # RunMonitor as a view over the registry
 
