@@ -234,18 +234,11 @@ def cache_slots(pos: jax.Array, cache_len: int, window: int) -> jax.Array:
                      jnp.minimum(pos, cache_len - 1))
 
 
-def gqa_decode(params: dict, x: jax.Array, cache: dict, cfg: ModelConfig,
-               pos: jax.Array, window: int = 0) -> Tuple[jax.Array, dict]:
-    """One-token decode against a cache.
-
-    x: (B, 1, d). cache: {"k","v"}: (B, C, Hkv, hd) where C is either the
-    full context length or the sliding window size (ring buffer).
-    pos: scalar int32 or (B,) int32 vector — absolute position of each
-    sequence's new token (per-slot under continuous batching).
-    """
-    b, s, _ = x.shape
-    assert s == 1
-    cache_len = cache["k"].shape[1]
+def _gqa_decode_qkv(params: dict, x: jax.Array, cfg: ModelConfig,
+                    pos: jax.Array):
+    """The new token's rotated q (B, 1, Hq, hd), its k, v (B, 1, Hkv, hd)
+    and the positions as a (B,) vector."""
+    b = x.shape[0]
     q = x @ params["wq"]
     k = x @ params["wk"]
     v = x @ params["wv"]
@@ -258,12 +251,16 @@ def gqa_decode(params: dict, x: jax.Array, cache: dict, cfg: ModelConfig,
     posv = pos[:, None]
     q = apply_rope(q, posv, cfg.rope_theta)
     k = apply_rope(k, posv, cfg.rope_theta)
-    slot = cache_slots(pos, cache_len, window)
-    rows = jnp.arange(b)
-    ck = cache["k"].at[rows, slot].set(k[:, 0])
-    cv = cache["v"].at[rows, slot].set(v[:, 0])
-    ck = constrain(ck, "kv_cache")
-    cv = constrain(cv, "kv_cache")
+    return q, k, v, pos
+
+
+def _gqa_decode_attend(params: dict, q: jax.Array, ck: jax.Array,
+                       cv: jax.Array, cfg: ModelConfig, pos: jax.Array,
+                       window: int) -> jax.Array:
+    """One query per sequence against its cache rows (B, C, Hkv, hd),
+    the new row included; rows past each sequence's position masked."""
+    b = q.shape[0]
+    cache_len = ck.shape[1]
     # validity: cache rows written so far, per sequence
     idx = jnp.arange(cache_len)
     if window > 0:
@@ -279,7 +276,57 @@ def gqa_decode(params: dict, x: jax.Array, cache: dict, cfg: ModelConfig,
     scores = jnp.where(valid[:, None, None, :], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(cv.dtype)
     out = jnp.einsum("bhgt,bthd->bhgd", probs, cv).reshape(b, 1, hq * cfg.head_dim)
-    return out @ params["wo"], {"k": ck, "v": cv}
+    return out @ params["wo"]
+
+
+def gqa_decode(params: dict, x: jax.Array, cache: dict, cfg: ModelConfig,
+               pos: jax.Array, window: int = 0) -> Tuple[jax.Array, dict]:
+    """One-token decode against a cache.
+
+    x: (B, 1, d). cache: {"k","v"}: (B, C, Hkv, hd) where C is either the
+    full context length or the sliding window size (ring buffer).
+    pos: scalar int32 or (B,) int32 vector — absolute position of each
+    sequence's new token (per-slot under continuous batching).
+    """
+    b, s, _ = x.shape
+    assert s == 1
+    cache_len = cache["k"].shape[1]
+    q, k, v, pos = _gqa_decode_qkv(params, x, cfg, pos)
+    slot = cache_slots(pos, cache_len, window)
+    rows = jnp.arange(b)
+    ck = cache["k"].at[rows, slot].set(k[:, 0])
+    cv = cache["v"].at[rows, slot].set(v[:, 0])
+    ck = constrain(ck, "kv_cache")
+    cv = constrain(cv, "kv_cache")
+    out = _gqa_decode_attend(params, q, ck, cv, cfg, pos, window)
+    return out, {"k": ck, "v": cv}
+
+
+def _with_new_rows(view: dict, new: dict, slot: jax.Array) -> dict:
+    """A read-only cache view (leaves (B, C, ...)) as the attention sees
+    it once each sequence's new row (``new``, leaves (B, ...)) sits at
+    row ``slot`` (B,): a select that the attention's reads can fuse.  A
+    row write would make the view a buffer of its own (on the TPU, a
+    copy of it in the write's layout, then another back)."""
+    def merge(v, n):
+        at = jnp.arange(v.shape[1])[None, :] == slot[:, None]
+        at = at.reshape(at.shape + (1,) * (v.ndim - 2))
+        return jnp.where(at, n[:, None].astype(v.dtype), v)
+    return jax.tree_util.tree_map(merge, view, new)
+
+
+def gqa_decode_view(params: dict, x: jax.Array, view: dict,
+                    cfg: ModelConfig, pos: jax.Array
+                    ) -> Tuple[jax.Array, dict]:
+    """:func:`gqa_decode` against a read-only dense view (B, C, Hkv, hd),
+    positions below C (no ring): the same values at the same shapes,
+    but the new rows are returned, (B, Hkv, hd) each, for the caller to
+    store, and the view is never written."""
+    q, k, v, pos = _gqa_decode_qkv(params, x, cfg, pos)
+    new = {"k": k[:, 0].astype(view["k"].dtype),
+           "v": v[:, 0].astype(view["v"].dtype)}
+    kv = _with_new_rows(view, new, cache_slots(pos, view["k"].shape[1], 0))
+    return _gqa_decode_attend(params, q, kv["k"], kv["v"], cfg, pos, 0), new
 
 
 # ---------------------------------------------------------------------------
@@ -365,6 +412,16 @@ def mla_prefill(params: dict, x: jax.Array, cfg: ModelConfig,
     return out, {"ckv": ckv, "kpe": k_pe[:, :, 0, :]}
 
 
+def _mla_decode_attend(params: dict, q_nope, q_rope, ckv, kpe,
+                       cfg: ModelConfig, pos: jax.Array) -> jax.Array:
+    cache_len = ckv.shape[1]
+    idx = jnp.arange(cache_len)
+    valid = idx[None, :] <= jnp.minimum(pos, cache_len - 1)[:, None]
+    mask = valid[:, None, None, :]                            # (b,1,s=1,C)
+    return _mla_attend(params, q_nope, q_rope, ckv, kpe[:, :, None, :], cfg,
+                       mask)
+
+
 def mla_decode(params: dict, x: jax.Array, cache: dict, cfg: ModelConfig,
                pos: jax.Array, window: int = 0):
     """cache: {"ckv": (B,C,r), "kpe": (B,C,dr)}.
@@ -379,8 +436,22 @@ def mla_decode(params: dict, x: jax.Array, cache: dict, cfg: ModelConfig,
     ckv = cache["ckv"].at[rows, slot].set(ckv_new[:, 0])
     kpe = cache["kpe"].at[rows, slot].set(k_pe_new[:, 0, 0, :])
     ckv = constrain(ckv, "mla_cache")
-    idx = jnp.arange(cache_len)
-    valid = idx[None, :] <= jnp.minimum(pos, cache_len - 1)[:, None]
-    mask = valid[:, None, None, :]                            # (b,1,s=1,C)
-    out = _mla_attend(params, q_nope, q_rope, ckv, kpe[:, :, None, :], cfg, mask)
+    out = _mla_decode_attend(params, q_nope, q_rope, ckv, kpe, cfg, pos)
     return out, {"ckv": ckv, "kpe": kpe}
+
+
+def mla_decode_view(params: dict, x: jax.Array, view: dict,
+                    cfg: ModelConfig, pos: jax.Array
+                    ) -> Tuple[jax.Array, dict]:
+    """:func:`mla_decode` against a read-only view {"ckv": (B,C,r),
+    "kpe": (B,C,dr)}, as :func:`gqa_decode_view` is to
+    :func:`gqa_decode`: returns the new rows, (B, r) and (B, dr)."""
+    b = x.shape[0]
+    pos = decode_positions(pos, b)
+    q_nope, q_rope, ckv_new, k_pe_new = _mla_qkv(params, x, cfg, pos[:, None])
+    new = {"ckv": ckv_new[:, 0].astype(view["ckv"].dtype),
+           "kpe": k_pe_new[:, 0, 0, :].astype(view["kpe"].dtype)}
+    kv = _with_new_rows(view, new, cache_slots(pos, view["ckv"].shape[1], 0))
+    out = _mla_decode_attend(params, q_nope, q_rope, kv["ckv"], kv["kpe"],
+                             cfg, pos)
+    return out, new

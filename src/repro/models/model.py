@@ -291,14 +291,21 @@ def init_cache(cfg: ModelConfig, batch: int, cache_len: int,
 # — every attention-cache leaf (k/v/ckv/kpe) has its block axes at tree
 # positions 1 and 2, so gather/scatter are uniform tree_maps.
 #
-# Parity by construction: ``gather_cache`` materializes the exact dense
-# (stack, B, C, ...) view the contiguous path holds (junk rows from
-# unallocated table slots are masked by decode's validity mask exactly
-# like the contiguous cache's zero rows), so the scheduler can feed the
-# gathered view through the SAME jitted ``decode_step`` executable as
-# the contiguous path — paged decode is bit-identical, not just close.
-# The TPU kernel that avoids the materialized gather is
+# Paged decode (``paged_decode_rows`` + ``scatter_decode_rows``, one
+# donated program in the scheduler) never holds a whole-cache view: each
+# layer of its scan gathers that layer's dense (B, C, ...) view — the
+# exact rows the contiguous cache holds at those positions, junk rows
+# from unallocated table entries masked by decode's validity mask like
+# the contiguous cache's zero rows — and attends over it through the
+# same layer body as ``decode_step``, with the new row merged in by a
+# select rather than written.  Only the B new rows leave the scan, and
+# they are written into the pool in place.  Same values, same shapes,
+# same reductions: paged decode is bit-identical to contiguous on the
+# CPU.  What it still reads is each slot's full table width (trash
+# blocks included); the kernel that reads live blocks only is
 # ``repro.kernels.decode_attention.paged_decode_attention``.
+# ``gather_cache`` materializes a whole view for the batch-1 admissions
+# that resume from shared prefix blocks.
 
 
 def supports_paged_cache(cfg: ModelConfig) -> bool:
@@ -364,23 +371,41 @@ def scatter_cache(pool: Dict[str, Any], cache: Dict[str, Any],
     return jax.tree_util.tree_map(s, pool, cache)
 
 
-def scatter_decode_rows(pool: Dict[str, Any], cache: Dict[str, Any],
+def _dense_view(leaf: jax.Array, layer: jax.Array,
+                tables: jax.Array) -> jax.Array:
+    """One layer's dense view, gathered straight from the stacked pool
+    leaf (L, NB, bs, ...) through ``tables`` (B, max_blocks) -> (B,
+    max_blocks * bs, ...).  Slicing the layer out first would make XLA
+    copy the whole layer of the pool before the gather."""
+    v = leaf[layer, tables]                    # (B, MB, bs, ...)
+    return v.reshape(v.shape[0], v.shape[1] * v.shape[2], *v.shape[3:])
+
+
+def scatter_decode_rows(pool: Dict[str, Any], rows: Dict[str, Any],
                         tables: jax.Array, pos: jax.Array) -> Dict[str, Any]:
-    """Write the rows ``decode_step`` just produced (one per sequence,
-    at that sequence's position) from the dense view back into the pool.
-    ``tables``: (B, MB) int32 — dead slots' all-trash tables land their
-    writes in the trash block."""
-    def s(pool_leaf, cache_leaf):
+    """Write each sequence's new decode row into the pool, in place when
+    the pool is donated.  ``rows``: the pool tree with leaves (L, B, ...)
+    from :func:`paged_decode_rows`; ``tables``: (B, max_blocks) int32 —
+    dead slots' all-trash tables land their rows in the trash block.
+    One ``dynamic_update_slice`` of (L, 1, 1, ...) per sequence, which
+    XLA does in place: a scatter over the block axes behind the layer
+    axis made it copy the whole pool leaf, in and out, on the TPU."""
+    b = tables.shape[0]
+    pos = L.decode_positions(pos, b)
+
+    def s(pool_leaf, row_leaf):
         bs = pool_leaf.shape[2]
-        c = cache_leaf.shape[2]
-        b = cache_leaf.shape[1]
-        slot = jnp.minimum(jnp.asarray(pos, jnp.int32), c - 1)
-        rows = jnp.arange(b)
-        blk = tables[rows, slot // bs]
-        vals = cache_leaf[:, rows, slot]       # (L, B, ...)
-        return pool_leaf.at[:, blk, slot % bs].set(
-            vals.astype(pool_leaf.dtype))
-    return jax.tree_util.tree_map(s, pool, cache)
+        slot = L.cache_slots(pos, tables.shape[1] * bs, 0)
+        blk = tables[jnp.arange(b), slot // bs]
+        off = slot % bs
+        row_leaf = row_leaf.astype(pool_leaf.dtype)
+        zeros = (0,) * (pool_leaf.ndim - 3)
+        for i in range(b):
+            pool_leaf = jax.lax.dynamic_update_slice(
+                pool_leaf, row_leaf[:, i, None, None],
+                (0, blk[i], off[i]) + zeros)
+        return pool_leaf
+    return jax.tree_util.tree_map(s, pool, rows)
 
 
 def copy_block(pool: Dict[str, Any], src: jax.Array,
@@ -389,20 +414,6 @@ def copy_block(pool: Dict[str, Any], src: jax.Array,
     a shared reference onto ``dst``; the data follows here)."""
     return jax.tree_util.tree_map(
         lambda leaf: leaf.at[:, dst].set(leaf[:, src]), pool)
-
-
-def paged_decode_step(params: Params, cfg: ModelConfig,
-                      pool: Dict[str, Any], tables: jax.Array,
-                      token: jax.Array, pos: jax.Array
-                      ) -> Tuple[jax.Array, Dict[str, Any]]:
-    """One decode step against the block pool: gather the dense view,
-    run the ordinary :func:`decode_step`, scatter the written rows back.
-    Convenience composition for tests/benchmarks — the scheduler runs
-    the three stages through its own jits so the middle one is the SAME
-    compiled executable as the contiguous path (the parity mechanism)."""
-    view = gather_cache(pool, tables)
-    logits, new_view = decode_step(params, cfg, view, token, pos)
-    return logits, scatter_decode_rows(pool, new_view, tables, pos)
 
 
 # ---------------------------------------------------------------------------
@@ -580,25 +591,62 @@ def decode_step(params: Params, cfg: ModelConfig, cache: Dict[str, Any],
         x, states = _scan(body, x, (params["layers"], cache["layers"]))
         new_cache = {"layers": states}
     else:
+        attn = L.mla_decode if cfg.attention == "mla" else L.gqa_decode
+
         def body(x, inp):
             lp, kv = inp
-            h = L.rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-            if cfg.attention == "mla":
-                a, kv2 = L.mla_decode(lp["attn"], h, kv, cfg, pos, window)
-            else:
-                a, kv2 = L.gqa_decode(lp["attn"], h, kv, cfg, pos, window)
-            x = x + a
-            h = L.rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-            if cfg.is_moe:
-                y, _ = moe_ffn(lp["moe"], h, cfg)
-            else:
-                y = L.mlp(lp["mlp"], h, cfg.mlp_type)
-            return x + y, kv2
+            return _decode_block(
+                lp, x, cfg, lambda p, h: attn(p, h, kv, cfg, pos, window))
         x, kvs = _scan(body, x, (params["layers"], cache["layers"]))
         new_cache = {"layers": kvs}
 
     logits = _lm_head(params, cfg, x)
     return logits[:, 0], new_cache
+
+
+def _decode_block(lp: Params, x: jax.Array, cfg: ModelConfig, attention
+                  ) -> Tuple[jax.Array, Dict[str, Any]]:
+    """One attention-cache layer at decode: ``attention(attn_params, h)
+    -> (a, kv)`` on the normed input, then the MLP or MoE.  Returns (x,
+    kv) — the body the contiguous and the paged decode share."""
+    h = L.rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+    a, kv2 = attention(lp["attn"], h)
+    x = x + a
+    h = L.rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+    if cfg.is_moe:
+        y, _ = moe_ffn(lp["moe"], h, cfg)
+    else:
+        y = L.mlp(lp["mlp"], h, cfg.mlp_type)
+    return x + y, kv2
+
+
+def paged_decode_rows(params: Params, cfg: ModelConfig,
+                      pool: Dict[str, Any], tables: jax.Array,
+                      token: jax.Array, pos: jax.Array
+                      ) -> Tuple[jax.Array, Dict[str, Any]]:
+    """Decode against the block pool without a whole-cache view: one scan
+    over the layers gathers each layer's dense view through ``tables``
+    (B, max_blocks) — a per-layer temporary, (B, max_blocks * bs, ...) —
+    and attends over it with each sequence's new row merged in
+    (``L.gqa_decode_view`` / ``L.mla_decode_view``: the values and shapes
+    :func:`decode_step` attends over, the view never written).  Returns
+    (logits (B, V), rows): ``rows`` is the pool tree with leaves
+    (L, B, ...), the new rows for :func:`scatter_decode_rows`."""
+    x = jnp.take(params["embed"], token, axis=0)
+    x = constrain(x, "activations")
+    pos = L.decode_positions(pos, token.shape[0])
+    attn = L.mla_decode_view if cfg.attention == "mla" else L.gqa_decode_view
+
+    def body(x, inp):
+        lp, layer = inp
+        view = jax.tree_util.tree_map(
+            lambda leaf: _dense_view(leaf, layer, tables), pool["layers"])
+        return _decode_block(lp, x, cfg,
+                             lambda p, h: attn(p, h, view, cfg, pos))
+
+    x, rows = _scan(body, x, (params["layers"], jnp.arange(cfg.n_layers)))
+    logits = _lm_head(params, cfg, x)
+    return logits[:, 0], {"layers": rows}
 
 
 def _hybrid_decode(params, cfg, cache, x, pos, window):

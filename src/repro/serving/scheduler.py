@@ -16,18 +16,20 @@ cache is replaced by a refcounted block pool
 :func:`repro.models.model.init_paged_cache` arrays) with per-slot block
 tables, and a content-hashed prefix cache on top — admissions whose
 leading full blocks match a cached prefix pin the SHARED blocks and
-prefill only the divergent suffix (``Engine.prefill_continue``).  The
-decode step stays bit-identical to the contiguous path by construction:
-the pool is gathered through the block tables into the exact dense view
-the contiguous cache holds (``max_len % block_size == 0`` makes the
-widths equal), that view runs through the SAME jitted ``decode_step``
-executable, and the freshly written rows scatter back into the pool.
-Junk rows gathered from recycled blocks sit beyond every sequence's
-valid length, where the decode validity mask zeroes them exactly as it
-zeroes the contiguous cache's stale rows.  Preemption frees the
-victim's blocks; resume re-pins (prefix blocks re-shared, the rest
-freshly allocated).  The contiguous path stays the default — parity is
-testable request-for-request (``tests/test_properties.py``).
+prefill only the divergent suffix (``Engine.prefill_continue``).  Paged
+decode is one donated program, ``paged_decode_step``: a scan over the
+layers gathers each layer's dense view through the block tables
+(``max_len % block_size == 0`` makes its width the contiguous cache's),
+attends over it with each slot's new row merged in — the values and
+shapes the contiguous ``decode_step`` attends over, through the same
+layer body — and writes only the new rows into the pool, in place.  No
+whole-cache view outlives a layer.  Junk rows gathered from recycled
+blocks sit beyond every sequence's valid length, where the decode
+validity mask zeroes them exactly as it zeroes the contiguous cache's
+stale rows.  Preemption frees the victim's blocks; resume re-pins
+(prefix blocks re-shared, the rest freshly allocated).  The contiguous
+path stays the default — parity is testable request-for-request
+(``tests/test_properties.py``).
 
 Admission (the v2 overhaul) no longer prefills one request per exact
 prompt length:
@@ -86,8 +88,9 @@ from jax.profiler import TraceAnnotation
 
 from ..core.events import EngineStepped
 from ..models.model import (copy_block, gather_cache, init_cache,
-                            init_paged_cache, scatter_cache,
-                            scatter_decode_rows, supports_paged_cache)
+                            init_paged_cache, paged_decode_rows,
+                            scatter_cache, scatter_decode_rows,
+                            supports_paged_cache)
 from .engine import (Engine, GenerationResult, PrefillJob, cache_leaf_name,
                      prefill_bucket)
 from .paging import BlockAllocator, PrefixCache
@@ -180,7 +183,8 @@ class BatchScheduler:
        then fill free slots in strict priority order — same-bucket
        groups via one batched prefill call, preempted requests via
        decode replay, long prompts by starting a chunk job;
-    3. *decode*: ONE jitted ``decode_step`` over the whole slot batch
+    3. *decode*: ONE jitted program over the whole slot batch
+       (``decode_step``, or ``paged_decode_step`` on the paged path)
        advances every live slot by a token.
 
     ``batched_prefill=False`` restores the v1 admission (one
@@ -254,7 +258,7 @@ class BatchScheduler:
             if max_len % block_size != 0:
                 # gathered view width (max_blocks * block_size) must equal
                 # the contiguous cache width — that equality is what lets
-                # both paths share one decode executable (bit parity)
+                # both paths attend over the same shapes (bit parity)
                 raise ValueError(
                     f"max_len ({max_len}) must be a multiple of "
                     f"block_size ({block_size})")
@@ -279,12 +283,29 @@ class BatchScheduler:
             self._tables_dirty = True
             self._tables_dev = None
             self._cache = None
+            # batch-1 views for prefix-hit admissions (decode never
+            # materializes one)
             self._gather = jax.jit(gather_cache)
-            self._scatter_rows = jax.jit(scatter_decode_rows,
-                                         donate_argnums=(0,))
+            # the pool's writers: prefill rows, CoW block copies, and
+            # decode's new rows — the last is traced into the decode
+            # program below when that first compiles
             self._scatter_prefill = jax.jit(scatter_cache,
                                             donate_argnums=(0,))
             self._copy = jax.jit(copy_block, donate_argnums=(0,))
+            self._scatter_rows = scatter_decode_rows
+            cfg = self.cfg
+
+            def paged_decode_step(params, pool, tables, wtables, token,
+                                  pos):
+                """One decode step of the slot batch against the donated
+                pool: attention over per-layer views, then each slot's
+                new row written in place (``wtables``: a dead slot's
+                row lands in the trash block)."""
+                logits, rows = paged_decode_rows(params, cfg, pool, tables,
+                                                 token, pos)
+                return logits, self._scatter_rows(pool, rows, wtables, pos)
+            self._decode_paged = jax.jit(paged_decode_step,
+                                         donate_argnums=(1,))
         else:
             self._prefix = None
             self._cache = init_cache(self.cfg, n_slots, self._cache_len,
@@ -838,7 +859,7 @@ class BatchScheduler:
         Traced as ``BatchScheduler.step`` with the phases as child spans:
         ``preempt``, ``admit`` (one child per admission), ``ensure_blocks``
         (paged), ``decode_inputs`` (host arrays), ``decode_dispatch``
-        (gather, decode, scatter, sample), ``readback`` (the sampled
+        (the decode program and the sampler), ``readback`` (the sampled
         tokens' pull to the host) and ``bookkeeping``."""
         with TraceAnnotation("BatchScheduler.step", step=self._steps,
                              live=self.occupancy(),
@@ -890,15 +911,9 @@ class BatchScheduler:
                                         self._trash)
             with TraceAnnotation("BatchScheduler.decode_dispatch"):
                 if self._paged:
-                    # gather pool -> dense view, decode with the SAME
-                    # jitted executable as the contiguous path (bit
-                    # parity), scatter the fresh rows back into the pool
-                    view = self._gather(self._pool, tables)
-                    logits, view = self.engine._decode(
-                        self.engine.params, cache=view, token=tokens,
-                        pos=pos)
-                    self._pool = self._scatter_rows(self._pool, view,
-                                                    wtables, pos)
+                    logits, self._pool = self._decode_paged(
+                        self.engine.params, self._pool, tables, wtables,
+                        tokens, pos)
                 else:
                     logits, self._cache = self.engine._decode(
                         self.engine.params, cache=self._cache, token=tokens,

@@ -130,10 +130,14 @@ def submission_rids(scenario: List[dict]) -> Dict[int, int]:
 
 def assert_parity(engine: Engine, scenario: List[dict], *,
                   n_blocks: Optional[int] = None,
-                  check_serial: bool = True) -> None:
+                  check_serial: bool = True,
+                  lockstep: bool = True) -> None:
     """The battery's core assertion: contiguous == paged+prefix ==
     paged-no-prefix (== serial), and the no-prefix paged event stream
-    matches contiguous modulo the paging gauges."""
+    matches contiguous modulo the paging gauges.  ``lockstep=False``
+    where the pool is tight enough to defer an admission that the
+    contiguous cache makes: the tokens still match, the step counts
+    need not."""
     ev_contig: list = []
     ev_paged: list = []
     contig = run_scenario(engine, scenario, paged=False, events=ev_contig)
@@ -145,8 +149,11 @@ def assert_parity(engine: Engine, scenario: List[dict], *,
     if check_serial:
         serial = serial_tokens(engine, scenario, submission_rids(scenario))
         assert serial == contig, f"contiguous diverged from serial: {serial}"
-    # without prefix reuse the step loop is lockstep-identical, so every
-    # event field except the paging gauges must match exactly
+    if not lockstep:
+        return
+    # without prefix reuse (and with blocks to spare) the step loop is
+    # lockstep-identical, so every event field except the paging gauges
+    # must match exactly
     assert len(ev_contig) == len(ev_paged)
     for a, b in zip(ev_contig, ev_paged):
         for f in ("t", "live", "queued", "generated", "prefilled",

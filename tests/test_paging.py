@@ -14,11 +14,17 @@ Acceptance criteria of the paging tentpole:
   * with prefix reuse off the paged event stream matches the
     contiguous one field-for-field modulo the new paging gauges.
 """
+import functools
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from paging_scenarios import (BLOCK, MAX_LEN, assert_parity, gen_scenario,
                               get_engine, run_scenario)
+from repro.models.model import (decode_step, gather_cache, init_paged_cache,
+                                paged_decode_rows, scatter_decode_rows)
 from repro.serving import (BatchScheduler, BlockAllocator, PagingError,
                           PrefixCache, RunMonitor, prefix_block_keys)
 from repro.core.events import EngineStepped
@@ -242,6 +248,92 @@ def test_contiguous_emits_zero_paging_gauges():
     sched.drain()
     assert events
     assert all(e.blocks_in_use == 0 and e.prefix_hits == 0 for e in events)
+
+
+# ---------------------------------------------------------------------------
+# the paged decode program
+
+
+@pytest.mark.parametrize("arch", ["gqa", "mla"])
+def test_paged_decode_matches_decode_step_on_the_gathered_view(arch):
+    """Per-layer views with the new rows merged in give decode_step's
+    logits bit for bit, and writing only the new rows leaves the pool
+    holding the view decode_step returns; a dead slot's row goes to the
+    trash block and its own blocks stay as they were."""
+    eng = get_engine(arch, 0.0)
+    cfg, params = eng.cfg, eng.params
+    rng = np.random.default_rng(7)
+    n_slots, mb = 3, MAX_LEN // BLOCK
+    trash = n_slots * mb
+    pool = jax.tree_util.tree_map(
+        lambda z: jnp.asarray(rng.standard_normal(z.shape), z.dtype),
+        init_paged_cache(cfg, trash + 1, BLOCK))
+    tables = jnp.asarray(rng.permutation(trash).reshape(n_slots, mb),
+                         jnp.int32)
+    token = jnp.asarray([[3], [7], [11]], jnp.int32)
+    pos = jnp.asarray([5, BLOCK * 2, MAX_LEN - 1], jnp.int32)
+    wtables = tables.at[2].set(trash)
+
+    want_logits, want_view = jax.jit(functools.partial(
+        decode_step, cfg=cfg))(params, cache=gather_cache(pool, tables),
+                               token=token, pos=pos)
+    logits, rows = jax.jit(functools.partial(paged_decode_rows, cfg=cfg))(
+        params, pool=pool, tables=tables, token=token, pos=pos)
+    assert jnp.array_equal(logits, want_logits)
+    new = jax.jit(scatter_decode_rows)(pool, rows, wtables, pos)
+    got_view = gather_cache(new, tables)
+    old_view = gather_cache(pool, tables)
+    for name, got in got_view["layers"].items():
+        want = want_view["layers"][name]
+        assert jnp.array_equal(got[:, :2], want[:, :2])
+        assert jnp.array_equal(got[:, 2], old_view["layers"][name][:, 2])
+        trash_rows = new["layers"][name][:, trash, (MAX_LEN - 1) % BLOCK]
+        assert jnp.array_equal(trash_rows, want[:, 2, MAX_LEN - 1])
+
+
+def _decoding_paged(n_req: int = 3) -> BatchScheduler:
+    """A paged scheduler whose next step is a pure decode step."""
+    sched = BatchScheduler(get_engine("gqa", 0.0), n_slots=3,
+                           max_len=MAX_LEN, paged_kv=True, block_size=BLOCK)
+    for i in range(n_req):
+        sched.submit(prompt_ids=list(range(1 + i, 12 + 3 * i)), max_new=8)
+    sched.step()
+    assert sched.occupancy() == n_req and not sched.queue_depth()
+    return sched
+
+
+def test_paged_decode_step_is_one_program_and_donates_the_pool():
+    """A paged decode step dispatches one program, jit_paged_decode_step,
+    and nothing else of the engine's or the pool's; the program aliases
+    its pool input to its output and the donated pool is gone after."""
+    sched = _decoding_paged()
+    eng = sched.engine
+    program = sched._decode_paged
+    calls = []
+
+    def spy(name, fn):
+        def call(*args, **kwargs):
+            calls.append((name, args))
+            return fn(*args, **kwargs)
+        return call
+
+    for name in ("_gather", "_scatter_prefill", "_copy", "_decode_paged"):
+        setattr(sched, name, spy(name, getattr(sched, name)))
+    decode = eng._decode
+    eng._decode = spy("engine._decode", decode)
+    pool = sched._pool
+    try:
+        sched.step()
+    finally:
+        eng._decode = decode
+    assert [name for name, _ in calls] == ["_decode_paged"]
+    assert all(leaf.is_deleted() for leaf in jax.tree_util.tree_leaves(pool))
+    shapes = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), calls[0][1])
+    lowered = program.lower(*shapes)
+    assert lowered.as_text().splitlines()[0].startswith(
+        "module @jit_paged_decode_step ")
+    assert "input_output_alias" in lowered.compile().as_text()
 
 
 # ---------------------------------------------------------------------------

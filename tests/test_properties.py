@@ -178,13 +178,17 @@ def test_paged_parity_property(seed, knobs):
 @settings(max_examples=4, deadline=None)
 def test_paged_parity_tight_pool_property(seed):
     """Random scenarios under a pool barely over one sequence wide:
-    eviction/exhaustion churn never changes a token."""
+    eviction/exhaustion churn never changes a token.  Such a pool may
+    defer an admission that the contiguous cache makes at once (seed 0:
+    one admission instead of two at step 6), so the step loops need not
+    run in lockstep."""
     from paging_scenarios import (BLOCK, MAX_LEN, assert_parity,
                                   gen_scenario, get_engine)
     rng = np.random.default_rng(seed)
     eng = get_engine("gqa", 1.0, 8)
     assert_parity(eng, gen_scenario(rng, n_req=5),
-                  n_blocks=MAX_LEN // BLOCK + 2, check_serial=False)
+                  n_blocks=MAX_LEN // BLOCK + 2, check_serial=False,
+                  lockstep=False)
 
 
 @given(st.lists(st.integers(1, 300), min_size=1, max_size=24),

@@ -12,6 +12,7 @@ test worker imports this file.
 import dataclasses
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,7 +21,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.kernels import ops
-from repro.models.model import decode_step, init_cache
+from repro.models.model import (decode_step, init_cache, init_paged_cache,
+                                paged_decode_rows, scatter_decode_rows)
 from repro.models.params import init_params
 
 
@@ -115,3 +117,43 @@ def test_decode_step_compiles_for_v5e(spec, one_chip):
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes > 0
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def test_paged_decode_writes_the_pool_in_place_on_v5e(spec, one_chip):
+    """qwen1.5-4b's widths (MHA, 20 x 128) cut to 4 layers, 8 slots x
+    2048 in blocks of 32: the paged decode program aliases its pool to
+    its output, copies no pool leaf, slices out no layer of the pool and
+    never holds a whole leaf's worth of view — only per-layer
+    temporaries."""
+    cfg = dataclasses.replace(get_config("qwen1.5-4b"), n_layers=4)
+    slots, blocks = 8, 2048 // 32
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.key(0), dtype=jnp.bfloat16)))
+    pool = on_chip(jax.eval_shape(lambda: init_paged_cache(
+        cfg, slots * blocks + 1, 32, dtype=jnp.bfloat16)))
+
+    def paged_decode_step(params, pool, tables, wtables, token, pos):
+        logits, rows = paged_decode_rows(params, cfg, pool, tables, token,
+                                         pos)
+        return logits, scatter_decode_rows(pool, rows, wtables, pos)
+
+    tables = spec((slots, blocks), jnp.int32)
+    compiled = jax.jit(paged_decode_step, donate_argnums=(1,)).lower(
+        params, pool, tables, tables, spec((slots, 1), jnp.int32),
+        spec((slots,), jnp.int32)).compile()
+    hlo = compiled.as_text()
+    leaf = pool["layers"]["k"]
+    leaf_bytes = leaf.size * leaf.dtype.itemsize
+    assert "input_output_alias" in hlo
+    assert compiled.memory_analysis().alias_size_in_bytes >= 2 * leaf_bytes
+    shape = ",".join(map(str, leaf.shape))
+    assert f"bf16[{shape}]" in hlo
+    assert not re.search(rf"= bf16\[{shape}\]\{{[^}}]*\}} copy\(", hlo)
+    assert f"bf16[{','.join(map(str, leaf.shape[1:]))}]" not in hlo
+    assert compiled.memory_analysis().temp_size_in_bytes < leaf_bytes
