@@ -56,19 +56,21 @@ def sample(requests, seed: int, k: int, chunk: int) -> List:
 
 
 def gaps(m: dict, seed: int, served_dtype, engine_seed: int, reqs: List,
-         mode: str = "f32", max_len: int = 2048) -> Dict[str, float]:
+         arch, mode: str = "f32", max_len: int = 2048) -> Dict[str, float]:
     """Widest score gap of the served tokens (``served_gap``) over every
-    served position of ``reqs``.  With ``mode="fp8"`` the control's own
-    choices stand in for the served tokens: ``served_gap`` is theirs, and
-    ``program_gap`` the served tokens'."""
+    served position of ``reqs``, against the reference of architecture
+    module ``arch`` (``Cell.arch``).  With
+    ``mode="fp8"`` the control's own choices stand in for the served
+    tokens: ``served_gap`` is theirs, and ``program_gap`` the served
+    tokens'."""
     seqs = [list(r.prompt) + list(r.out[:-1]) for r in reqs]
     want = [range(len(r.prompt) - 1, len(r.prompt) - 1 + r.n_out)
             for r in reqs]
     pad = min(2048, max_len)
     kw = dict(pad_to=pad, block=min(512, pad))
-    ref = reference.logits(m, seed, served_dtype, seqs, want, **kw)
-    ctl = (reference.logits(m, seed, served_dtype, seqs, want, mode="fp8",
-                            **kw) if mode == "fp8" else None)
+    ref = reference.logits(m, seed, served_dtype, seqs, want, arch, **kw)
+    ctl = (reference.logits(m, seed, served_dtype, seqs, want, arch,
+                            mode="fp8", **kw) if mode == "fp8" else None)
     V = m["vocab_size"]
     served, control, n = 0.0, 0.0, 0
     for i, r in enumerate(reqs):

@@ -52,7 +52,8 @@ class CompileCounter:
 class RunData:
     """What a metric reader sees of a run."""
 
-    def __init__(self, cell, window, counts, peaks, trace, chunk):
+    def __init__(self, cell, window, counts, peaks, trace, chunk,
+                 spans=None):
         self.kind = cell.mix["kind"]
         self.seconds = window.seconds
         self.drain_deadline = window.drain_deadline
@@ -62,6 +63,7 @@ class RunData:
         self.counts = counts
         self.peaks = peaks
         self.trace = trace
+        self.spans = spans      # the program's own spans, traced runs only
         self.chunk = chunk
         self._work = None
 
@@ -78,7 +80,7 @@ class RunData:
         prefill, and one per live slot from each step's decode."""
         firsts = sum(1 for r in self.requests
                      if r.first is not None and r.first <= self.seconds)
-        return firsts + sum(s.generated for s in self.steps
+        return firsts + sum(s.counters["generated"] for s in self.steps
                             if s.end <= self.seconds)
 
     @property
@@ -163,7 +165,6 @@ def execute(cell, seed: int, seconds: float, trace: int, env: dict, *,
     control's, judged in the program's place, and the program's own
     ``program_gap`` beside them."""
     from harness import check, serve, traffic
-    from harness.counts import Counts
     compiles = env["compiles"]
     devices = env["devices"]
     cfg = spec.model_config(cell.config)
@@ -204,7 +205,7 @@ def execute(cell, seed: int, seconds: float, trace: int, env: dict, *,
     stats = devices[0].memory_stats() or {}
     peak = int(stats.get("peak_bytes_in_use", 0))
     n_steps = len(window.steps)
-    preempted = sum(s.preempted for s in window.steps)
+    preempted = sum(s.counters["preempted"] for s in window.steps)
     print(f"compiles_in_window={compiles.n} steps={n_steps} "
           f"preempted={preempted}", flush=True)
     say(f"compiles_in_window={compiles.n} steps={n_steps} "
@@ -213,18 +214,23 @@ def execute(cell, seed: int, seconds: float, trace: int, env: dict, *,
     del engine, sched
     gc.collect()
 
-    counts = Counts.of(m, sv["dtype"])
-    summary = None
+    counts = cell.arch.counts(m, sv["dtype"])
+    summary = program = None
     if trace_dir is not None:
+        from harness import spans
         from harness import trace as trace_mod
+        t = time.perf_counter()
         files = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
         if files and keep_trace:
             shutil.copy(files[0], keep_trace)
         if files:
             dev_ops, host = trace_mod.read_xspace(files[0])
             summary = trace_mod.summarize(dev_ops, host)
+            program = spans.summarize(dev_ops, host)
         shutil.rmtree(trace_dir, ignore_errors=True)
-    run = RunData(cell, window, counts, env["peaks"], summary, chunk)
+        say(f"trace read {time.perf_counter() - t:.1f} s")
+    run = RunData(cell, window, counts, env["peaks"], summary, chunk,
+                  spans=program)
 
     due = run.due_requests()
     attempted = len(due)
@@ -236,7 +242,7 @@ def execute(cell, seed: int, seconds: float, trace: int, env: dict, *,
     numbers: Dict[str, float] = {}
     if picked:
         numbers = check.gaps(m, seed, serve.DTYPES[sv["dtype"]],
-                             serve.engine_seed(seed), picked,
+                             serve.engine_seed(seed), picked, cell.arch,
                              mode="fp8" if control else "f32",
                              max_len=max_len)
         say(f"check: {len(picked)} requests, {int(numbers['tokens'])} "

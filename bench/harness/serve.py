@@ -7,6 +7,8 @@ An ``agent`` mix is submitted open loop from a thread of its own, each
 request at its due time; a ``batch`` mix keeps a backlog topped up after
 every step.  Each step's end is stamped on the host clock: a step ends in
 host reads of its sampled tokens, so the stamp is device-synchronised.
+Each step keeps the int counters of the program's ``EngineStepped`` for
+it, and each request the program's admission stamp.
 """
 from __future__ import annotations
 
@@ -34,12 +36,19 @@ class Step:
     idx: int
     start: float        # seconds after the window opened
     end: float
-    live: int
-    prefilled: int
-    generated: int
-    admissions: int
+    admissions: int     # from the prefix cache's stats, over the step
     reused: int
-    preempted: int
+    # every int field of the step's EngineStepped by its own name
+    # (``live``, ``generated``, ``prefill_rows``, ...): a reader of a
+    # counter the program adds later needs no edit here
+    counters: Dict[str, int]
+
+
+def int_fields(event) -> Dict[str, int]:
+    """The int fields of a dataclass event (``EngineStepped``)."""
+    return {f.name: v for f in dataclasses.fields(event)
+            if isinstance(v := getattr(event, f.name), int)
+            and not isinstance(v, bool)}
 
 
 def build(cell, cfg, seed: int):
@@ -48,7 +57,7 @@ def build(cell, cfg, seed: int):
     from repro.serving.scheduler import BatchScheduler
     sv = cell.serving
     dtype = DTYPES[sv["dtype"]]
-    params = weights.make_params(cell.model, seed, dtype)
+    params = weights.make_params(cell.model, seed, dtype, cell.arch)
     jax.block_until_ready(params)
     engine = Engine(cfg, params=params, seed=engine_seed(seed), dtype=dtype,
                     temperature=float(sv["temperature"]),
@@ -222,11 +231,10 @@ def run_window(sched, mix: dict, reqs, seconds: float, *,
             st = sched.paging_stats()
             adm = st["hits"] + st["misses"]
             ev = events.last
-            steps.append(Step(idx=idx, start=ts, end=te, live=ev.live,
-                              prefilled=ev.prefilled, generated=ev.generated,
+            steps.append(Step(idx=idx, start=ts, end=te,
                               admissions=adm - adm_prev,
                               reused=st["tokens_reused"] - reused_prev,
-                              preempted=ev.preempted))
+                              counters=int_fields(ev)))
             adm_prev, reused_prev = adm, st["tokens_reused"]
             if finished:
                 with lock:
@@ -258,6 +266,8 @@ def run_window(sched, mix: dict, reqs, seconds: float, *,
             q = sched.requests.get(r.rid)
             if q is not None and q.t_first_token:
                 r.first = q.t_first_token - t0
+            # the program's admission stamp; None if never admitted
+            r.admit = q.t_admit - t0 if q is not None and q.t_admit else None
     _mark_admissions(tracked, steps)
     return Window(requests=tracked, steps=steps, seconds=seconds,
                   drain_deadline=deadline if agent else seconds)

@@ -7,7 +7,8 @@ step prefilled."""
 
 def read(run):
     steps = [s for s in run.steps if s.end <= run.seconds]
-    rows = [getattr(s, "prefill_rows", None) for s in steps]
+    rows = [s.counters.get("prefill_rows") for s in steps]
     if not steps or None in rows or not sum(rows):
         return None
-    return 100.0 * (1.0 - sum(s.prefilled for s in steps) / sum(rows))
+    return 100.0 * (1.0 - sum(s.counters["prefilled"] for s in steps)
+                    / sum(rows))

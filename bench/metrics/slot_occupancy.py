@@ -6,4 +6,5 @@ def read(run):
     steps = [s for s in run.steps if s.end <= run.seconds]
     if not steps:
         return None
-    return 100.0 * sum(s.live for s in steps) / (len(steps) * run.n_slots)
+    return 100.0 * sum(s.counters["live"] for s in steps) / (
+        len(steps) * run.n_slots)

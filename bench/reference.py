@@ -1,16 +1,15 @@
-"""Plain float32 reference of a dense decoder, and its float8 control.
+"""Plain float32 reference of a decoder, and its float8 control.
 
 The forward pass a configuration's ``model`` block describes, written
 out in ``jax.numpy`` with ``default_matmul_precision("highest")``: token
-embedding; per layer, RMSNorm, Q/K/V projections (with bias where the
-configuration has it), rotary embedding on halves of each head, causal
-attention with grouped K/V heads (one K/V head is MQA, as many as the
-query heads is MHA), the output projection and the residual; RMSNorm
-and a GELU (tanh form) or SwiGLU MLP and the residual; a final RMSNorm
-and the head.  No cache, no batching, no padding tricks.  It imports
-nothing of the program: its weights are drawn from the seed by the
-benchmark's own generator, layer by layer, so that a published width fits
-beside nothing else on the chip.
+embedding; each layer in the program's order, by the configuration's
+architecture module (``bench/archs/<arch>.py``: the dense decoder's is
+``archs/dense.py``); a final RMSNorm and the head.  No cache, no
+batching, no padding tricks.  It imports nothing of the program: its
+weights are drawn from the seed by the benchmark's own generator, layer
+by layer, so that a published width fits beside nothing else on the
+chip.  What every architecture shares is here: the float8 rounding of a
+matrix product, RMSNorm and blocked causal attention.
 
 ``mode="fp8"`` is the control: the same pass with every matrix product's
 operands rounded to float8 (e4m3, scaled to the tensor's largest value),
@@ -20,7 +19,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Dict, List, Sequence
+from typing import List, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -47,40 +46,14 @@ def _rms(x, scale, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * scale
 
 
-def _rope(x, pos, theta):
-    """x: (T, H, hd); rotate the first and second halves of each head."""
-    hd = x.shape[-1]
-    freqs = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
-    ang = pos[:, None].astype(jnp.float32) * freqs           # (T, hd/2)
-    cos, sin = jnp.cos(ang)[:, None], jnp.sin(ang)[:, None]
-    x1, x2 = x[..., :hd // 2], x[..., hd // 2:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
-
-
-def _gelu_tanh(x):
-    return 0.5 * x * (1.0 + jnp.tanh(math.sqrt(2.0 / math.pi)
-                                     * (x + 0.044715 * x ** 3)))
-
-
-@functools.partial(jax.jit, static_argnames=("m", "fp8", "block"))
-def _layer(h, w, *, m, fp8, block):
-    """One decoder layer over a whole sequence h: (T, d), T % block == 0."""
-    m = dict(m)
-    T = h.shape[0]
-    H, Hkv, hd = m["n_heads"], m["n_kv_heads"], m["head_dim"]
+def attention(q, k, v, block):
+    """Causal attention of q: (T, H, hd) over k, v: (T, Hkv, hd) with
+    grouped K/V heads (H % Hkv == 0), scores scaled by 1/sqrt(hd), one
+    block of ``block`` query rows at a time (T % block == 0).  Returns
+    (T, H * v's head size)."""
+    T, H, hd = q.shape
+    Hkv = k.shape[1]
     g = H // Hkv
-    eps = m["norm_eps"]
-    pos = jnp.arange(T)
-    x = _rms(h, w["attn_norm"], eps)
-    q, k, v = (_mm(x, w["wq"], fp8), _mm(x, w["wk"], fp8),
-               _mm(x, w["wv"], fp8))
-    if m.get("qkv_bias"):
-        q, k, v = q + w["bq"], k + w["bk"], v + w["bv"]
-    q = _rope(q.reshape(T, H, hd), pos, m["rope_theta"])
-    k = _rope(k.reshape(T, Hkv, hd), pos, m["rope_theta"])
-    v = v.reshape(T, Hkv, hd)
-    if fp8:
-        q, k, v = _q8(q), _q8(k), _q8(v)
     qb = q.reshape(T // block, block, Hkv, g, hd)
 
     def attend(args):
@@ -92,14 +65,7 @@ def _layer(h, w, *, m, fp8, block):
         return jnp.einsum("kgqt,tkd->qkgd", p, v)
 
     starts = jnp.arange(T // block) * block
-    o = jax.lax.map(attend, (qb, starts)).reshape(T, H * hd)
-    h = h + _mm(o, w["wo"], fp8)
-    x = _rms(h, w["mlp_norm"], eps)
-    if m["mlp_type"] == "swiglu":
-        u = jax.nn.silu(_mm(x, w["w_gate"], fp8)) * _mm(x, w["w_up"], fp8)
-    else:
-        u = _gelu_tanh(_mm(x, w["w_up"], fp8))
-    return h + _mm(u, w["w_down"], fp8)
+    return jax.lax.map(attend, (qb, starts)).reshape(T, H * v.shape[-1])
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "fp8"))
@@ -107,41 +73,54 @@ def _head(h, norm, head, *, eps, fp8):
     return _mm(_rms(h, norm, eps), head, fp8)
 
 
+def _freeze(v):
+    """A hashable copy of a configuration value: a dict as its sorted
+    (key, value) pairs and a list as a tuple, all the way down."""
+    if isinstance(v, dict):
+        return tuple(sorted((k, _freeze(x)) for k, x in v.items()))
+    if isinstance(v, list):
+        return tuple(_freeze(x) for x in v)
+    return v
+
+
 def logits(m: dict, seed: int, served_dtype, seqs: Sequence[List[int]],
-           want: Sequence[Sequence[int]], mode: str = "f32",
+           want: Sequence[Sequence[int]], arch, mode: str = "f32",
            block: int = 512, pad_to: int = 2048) -> List[jax.Array]:
     """Logits (len(want[i]), V) at positions ``want[i]`` of each token
     sequence ``seqs[i]``: the logits there predict the next token.
 
-    The weights are the benchmark's seeded draw in ``served_dtype``,
-    widened to float32.  Each sequence is padded at its end to a multiple
-    of ``pad_to`` (padding follows every real position, so under the
-    causal mask it changes nothing); the layers run one at a time over
-    every sequence, so one layer's weights are on the device at once."""
+    ``arch`` is the configuration's architecture module (``Cell.arch``),
+    which gives the layers.  The weights are the benchmark's seeded draw
+    in ``served_dtype``, widened to float32.  Each sequence is padded at
+    its end to a multiple of ``pad_to`` (padding follows every real
+    position, so under the causal mask it changes nothing); the layers
+    run one at a time over every sequence, so one layer's weights are on
+    the device at once."""
     fp8 = mode == "fp8"
-    frozen = tuple(sorted((k, v) for k, v in m.items()
-                          if not isinstance(v, (dict, list))))
+    frozen = _freeze(m)
     f32 = jnp.float32
 
     def up(x):
         return x.astype(f32)
 
     with jax.default_matmul_precision("highest"):
-        embed = up(weights.top_leaf(m, seed, "embed", served_dtype))
+        embed = up(weights.top_leaf(m, seed, "embed", served_dtype, arch))
         hs = []
         for ids in seqs:
             T = -(-len(ids) // pad_to) * pad_to
             tok = jnp.asarray(list(ids) + [0] * (T - len(ids)), jnp.int32)
             hs.append(embed[tok])
         head = (embed.T if m.get("tie_embeddings") else
-                up(weights.top_leaf(m, seed, "lm_head", served_dtype)))
+                up(weights.top_leaf(m, seed, "lm_head", served_dtype, arch)))
         del embed
-        for layer in range(m["n_layers"]):
+        for layer in range(len(arch.layers(m))):
             w = {k: up(v) for k, v in weights.layer_leaves(
-                m, seed, layer, served_dtype).items()}
-            hs = [_layer(h, w, m=frozen, fp8=fp8, block=block) for h in hs]
+                m, seed, layer, served_dtype, arch).items()}
+            hs = [arch.layer(h, w, m=frozen, fp8=fp8, block=block)
+                  for h in hs]
             del w
-        norm = up(weights.top_leaf(m, seed, "final_norm", served_dtype))
+        norm = up(weights.top_leaf(m, seed, "final_norm", served_dtype,
+                                   arch))
         out = []
         for h, pos in zip(hs, want):
             rows = h[jnp.asarray(list(pos), jnp.int32)]

@@ -38,11 +38,13 @@ TINY_LIMIT = 0.1
 
 def make_root(tmp: Path, kind: str, model=None) -> Path:
     """A checkout-like tree: BENCHMARK.json with a ``tiny.<kind>`` cell,
-    its configuration, mix and check file, and the real metric readers."""
+    its configuration, mix and check file, and the real metric readers
+    and architecture modules."""
     root = Path(tmp)
     (root / "bench").mkdir(parents=True, exist_ok=True)
-    shutil.copytree(BENCH / "metrics", root / "bench" / "metrics",
-                    dirs_exist_ok=True)
+    for d in ("metrics", "archs"):
+        shutil.copytree(BENCH / d, root / "bench" / d, dirs_exist_ok=True,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     for d in ("configs", "traffic", "cells"):
         (root / "bench" / d).mkdir(exist_ok=True)
     conf = {"name": "tiny", "source": "test", "model": model or TINY_MODEL,
@@ -81,3 +83,21 @@ def run(root: Path, kind: str, capsys, seed: int = 2 ** 31 + 77,
     assert rc == 0
     out = capsys.readouterr().out.strip().splitlines()
     return json.loads(out[-1])
+
+
+def window(root: Path, kind: str, seed: int, seconds: float = 2.0):
+    """Set-up, warm-up and window of the tiny cell as a run makes them,
+    with no check: returns the cell, the window and the scheduler."""
+    from harness import serve, spec, traffic
+    cell = spec.find_cell(f"tiny.{kind}", root=root)
+    m, sv, mix = cell.model, cell.serving, cell.mix
+    vocab, max_len = m["vocab_size"], int(sv["max_len"])
+    _, sched = serve.build(cell, spec.model_config(cell.config), seed)
+    serve.warm_up(sched, traffic.warm_shapes(
+        mix, seed, seconds, vocab, max_len, int(sv["prefill_chunk"])),
+        vocab, seed)
+    if mix["kind"] == "agent":
+        reqs = traffic.agent_requests(mix, seed, seconds, vocab, max_len)
+    else:
+        reqs = traffic.batch_requests(mix, seed, vocab, max_len)
+    return cell, serve.run_window(sched, mix, reqs, seconds), sched

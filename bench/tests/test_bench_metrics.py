@@ -7,7 +7,6 @@ import pytest
 
 from bench_tiny import ROOT
 from harness import account, spec, stats
-from harness.counts import Counts
 from harness.serve import Step
 from harness.traffic import Req
 
@@ -35,7 +34,7 @@ class FakeRun:
     def window_output_tokens(self):
         firsts = sum(1 for r in self.requests
                      if r.first is not None and r.first <= self.seconds)
-        return firsts + sum(s.generated for s in self.steps
+        return firsts + sum(s.counters["generated"] for s in self.steps
                             if s.end <= self.seconds)
 
     @property
@@ -48,6 +47,13 @@ def _req(due, first, done, n_out, rid, prompt=3, submit=None):
     r.first, r.done, r.submit = first, done, submit
     r.out = [5] * n_out if done is not None else None
     return r
+
+
+def _step(i, start, end, live=0, prefilled=0, generated=0, admissions=0,
+          reused=0):
+    return Step(i, start, end, admissions, reused,
+                {"live": live, "prefilled": prefilled,
+                 "generated": generated, "preempted": 0})
 
 
 def test_percentile_is_nearest_rank():
@@ -74,7 +80,7 @@ def test_unfinished_request_enters_the_tails_at_the_drain_limit():
 
 def test_rate_is_over_the_whole_window():
     reqs = [_req(0, 0.1, 9.0, 10, 0), _req(0, 0.2, 12.0, 10, 1)]
-    steps = [Step(i, i * 0.5, i * 0.5 + 0.4, 2, 0, 2, 0, 0, 0)
+    steps = [_step(i, i * 0.5, i * 0.5 + 0.4, live=2, generated=2)
              for i in range(24)]                    # 20 end inside 10 s
     run = FakeRun(reqs, steps, kind="batch")
     # 2 first tokens + 20 steps x 2 decoded, over 10 s even though the
@@ -90,8 +96,9 @@ def test_lateness_and_prefix_share():
     reqs = [_req(1.0, 2.0, 3.0, 2, 0, prompt=100, submit=1.002),
             _req(2.0, 3.0, 4.0, 2, 1, prompt=300, submit=2.010)]
     reqs[0].admit_step, reqs[1].admit_step = 0, 1
-    steps = [Step(0, 0, 1, 0, 100, 0, 1, 0, 0),
-             Step(1, 1, 2, 1, 200, 1, 1, 96, 0)]
+    steps = [_step(0, 0, 1, prefilled=100, admissions=1),
+             _step(1, 1, 2, live=1, prefilled=200, generated=1,
+                   admissions=1, reused=96)]
     run = FakeRun(reqs, steps)
     assert read("gen_lateness_p99_ms", run) == pytest.approx(10.0, abs=1e-6)
     assert read("prefix_reuse_share", run) == pytest.approx(96 / 400 * 100)
@@ -103,7 +110,7 @@ def test_step_accounting_chunks_and_decodes():
     # steps 5 and 6 (contexts 11 and 12)
     r = _req(0, 5.5, 6.5, 3, 0, prompt=10)
     r.admit_step, r.reused = 3, 2
-    steps = [Step(i, i, i + 0.9, 0, 0, 0, 0, 0, 0) for i in range(8)]
+    steps = [_step(i, i, i + 0.9) for i in range(8)]
     work = account.step_work(FakeRun([r], steps))
     assert [list(x) for x in work[3].prefill_positions] == [[2, 3, 4, 5]]
     assert [list(x) for x in work[4].prefill_positions] == [[6, 7, 8, 9]]
@@ -112,6 +119,6 @@ def test_step_accounting_chunks_and_decodes():
     assert not work[5].pure_decode
     m = {"d_model": 8, "vocab_size": 16, "n_layers": 2, "head_dim": 2,
          "n_heads": 4, "n_kv_heads": 2, "d_ff": 8, "mlp_type": "gelu"}
-    c = Counts.of(m, "bfloat16")
+    c = spec.arch("dense").counts(m, "bfloat16")
     assert account.step_flops(work[3], c) == sum(c.token_flops(p + 1)
                                                  for p in range(2, 6))

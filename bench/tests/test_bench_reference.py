@@ -18,12 +18,13 @@ from harness import spec, weights
 GQA = bench_tiny.TINY_MODEL
 MQA = dict(GQA, n_kv_heads=1, mlp_type="gelu", qkv_bias=False)
 MHA = dict(GQA, n_kv_heads=4, rope_theta=5e6)
+DENSE = spec.arch("dense")
 
 
 def _engine(m, seed, dtype):
     from repro.serving.engine import Engine
     cfg = spec.model_config({"name": "t", "model": m})
-    params = weights.make_params(m, seed, dtype)
+    params = weights.make_params(m, seed, dtype, DENSE)
     return Engine(cfg, params=params, seed=seed, dtype=dtype,
                   prefill_chunk=16)
 
@@ -44,7 +45,7 @@ def test_prefill_and_decode_match_the_reference(m):
         got.append(logits[0])
     want = reference.logits(m, seed, jnp.float32, [ids + extra],
                             [range(len(ids) - 1, len(ids) + len(extra))],
-                            block=16, pad_to=64)[0]
+                            DENSE, block=16, pad_to=64)[0]
     got = jnp.stack(got)
     scale = float(jnp.max(jnp.abs(want)))
     assert float(jnp.max(jnp.abs(got - want))) <= 1e-4 * scale
@@ -52,9 +53,9 @@ def test_prefill_and_decode_match_the_reference(m):
 
 def test_weight_draw_layer_by_layer_equals_the_whole():
     m = GQA
-    whole = weights.make_params(m, 9, jnp.bfloat16)
+    whole = weights.make_params(m, 9, jnp.bfloat16, DENSE)
     for layer in range(m["n_layers"]):
-        one = weights.layer_leaves(m, 9, layer, jnp.bfloat16)
+        one = weights.layer_leaves(m, 9, layer, jnp.bfloat16, DENSE)
         for name, leaf in one.items():
             part = "attn" if name in ("wq", "wk", "wv", "wo", "bq", "bk",
                                       "bv") else "mlp"
@@ -62,8 +63,9 @@ def test_weight_draw_layer_by_layer_equals_the_whole():
                    else whole["layers"][part][name])
             assert jnp.array_equal(src[layer], leaf), name
     assert jnp.array_equal(whole["embed"],
-                           weights.top_leaf(m, 9, "embed", jnp.bfloat16))
-    other = weights.make_params(m, 10, jnp.bfloat16)
+                           weights.top_leaf(m, 9, "embed", jnp.bfloat16,
+                                            DENSE))
+    other = weights.make_params(m, 10, jnp.bfloat16, DENSE)
     assert not jnp.array_equal(whole["embed"], other["embed"])
 
 

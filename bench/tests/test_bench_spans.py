@@ -4,7 +4,6 @@ synthesised trace, the three readers of the program's fields, and, on
 whole tiny runs, the program's admission stamps against the harness's
 first-in-first-out rebuild."""
 import dataclasses
-import importlib.util
 
 import pytest
 
@@ -110,11 +109,12 @@ def test_step_idle_reads_the_program_step_spans():
 
 
 def _step(i, prefilled, rows, end=1.0):
-    s = Step(idx=i, start=end - 0.1, end=end, live=1, prefilled=prefilled,
-             generated=1, admissions=0, reused=0, preempted=0)
+    counters = {"live": 1, "prefilled": prefilled, "generated": 1,
+                "preempted": 0}
     if rows is not None:
-        s.prefill_rows = rows
-    return s
+        counters["prefill_rows"] = rows
+    return Step(idx=i, start=end - 0.1, end=end, admissions=0, reused=0,
+                counters=counters)
 
 
 def test_prefill_pad_share_is_the_padded_rows_share():
@@ -150,23 +150,16 @@ def test_queue_wait_counts_unadmitted_requests_at_the_drain_limit():
     assert read("queue_wait_p90_ms", parent) is None
 
 
-def _tool():
-    path = bench_tiny.BENCH / "tools" / "program_metrics.py"
-    s = importlib.util.spec_from_file_location("program_metrics", path)
-    mod = importlib.util.module_from_spec(s)
-    s.loader.exec_module(mod)
-    return mod
-
-
 @pytest.mark.parametrize("kind", ["agent", "batch"])
 def test_program_stamps_agree_with_the_fifo_rebuild(tmp_path, kind):
     """The harness rebuilds admissions first in, first out
     (``serve._mark_admissions``); the program stamps its own.  Over whole
     tiny runs of both mixes they agree, and the readers read them."""
     root = bench_tiny.make_root(tmp_path, kind)
-    cell = spec.find_cell(f"tiny.{kind}", root=root)
-    env = hm.open_device(cell, require_accelerator=False)
-    out, window, sched = _tool().measure(cell, 2 ** 31 + 5, 2.0, False, env)
+    cell, window, sched = bench_tiny.window(root, kind, 2 ** 31 + 5)
+    sv = cell.serving
+    run = hm.RunData(cell, window, cell.arch.counts(cell.model, sv["dtype"]),
+                     None, None, int(sv["prefill_chunk"]))
     warm = sched._steps - len(window.steps)
     admitted = [r for r in window.requests if r.admit_step is not None]
     assert admitted
@@ -179,6 +172,6 @@ def test_program_stamps_agree_with_the_fifo_rebuild(tmp_path, kind):
             assert q.reused == r.reused, r.rid
     if kind == "agent":
         assert any(sched.requests[r.rid].reused for r in admitted)
-        assert out["queue_wait_p90_ms"] >= 0
-    assert 0 < out[f"prefill_pad_share.{kind}"] < 100
-    assert out[f"step_idle_ms.{kind}"] is None          # no device plane
+        assert read("queue_wait_p90_ms", run) >= 0
+    assert 0 < read(f"prefill_pad_share.{kind}", run) < 100
+    assert read(f"step_idle_ms.{kind}", run) is None    # not traced

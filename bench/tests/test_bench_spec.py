@@ -9,8 +9,7 @@ import jax.numpy as jnp
 import pytest
 
 from bench_tiny import BENCH, ROOT
-from harness import spec, weights
-from harness.counts import Counts
+from harness import spec
 
 B = json.loads((ROOT / "BENCHMARK.json").read_text())
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -81,7 +80,8 @@ def test_counts_match_the_programs_parameter_shapes(name):
     sizes = {"/".join(str(k.key) for k in path): leaf.size
              for path, leaf in
              jax.tree_util.tree_leaves_with_path(shapes)}
-    c = Counts.of(m, "bfloat16")
+    arch = spec.arch(m["arch_type"])
+    c = arch.counts(m, "bfloat16")
     assert c.weight_params == sum(sizes.values()) - sizes["embed"]
     matmuls = sum(v for k, v in sizes.items()
                   if k.split("/")[-1].startswith("w") or k == "lm_head")
@@ -89,8 +89,8 @@ def test_counts_match_the_programs_parameter_shapes(name):
     L, kv = m["n_layers"], m["n_kv_heads"] * m["head_dim"]
     assert c.kv_bytes_per_token == 2 * L * kv * 2
     # the benchmark's own draw has the program's layout, leaf for leaf
-    mine = {path: (m["n_layers"], *shape) if path.startswith("layers/")
-            else shape for path, shape, _ in weights.leaf_specs(m)}
+    mine = {path: shape if stack is None else (stack, *shape)
+            for path, stack, shape, _ in arch.leaf_specs(m)}
     assert {k: tuple(v.shape) for k, v in
             zip(sizes, jax.tree_util.tree_leaves(shapes))} == {
         k: tuple(v) for k, v in mine.items()}

@@ -74,7 +74,8 @@ def run():
             - a.seconds if w.requests else 0.0,
             "ttft_p90_early_s": ttft(early), "ttft_p90_late_s": ttft(late),
             "steps": len(w.steps),
-            "mean_live": sum(s.live for s in w.steps) / max(1, len(w.steps)),
+            "mean_live": sum(s.counters["live"] for s in w.steps)
+            / max(1, len(w.steps)),
             "wall_s": time.perf_counter() - t}), flush=True)
     return 0
 
