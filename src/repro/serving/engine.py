@@ -356,6 +356,22 @@ def _sample_row(logits: jax.Array, key: jax.Array, temperature: float,
     return jax.random.categorical(key, logits, axis=-1)
 
 
+def keyed_sampler(base_key: jax.Array, temperature: float, top_p: float):
+    """The keyed sampler's traced body: ``sampler(logits (B, V), rids
+    (B,), steps (B,)) -> tokens (B,)``, row ``i`` drawn with ``base_key``
+    folded with ``rids[i]``, then with ``steps[i]``.  The knobs steer
+    python-level branches, so they are baked into whatever traces it."""
+    row = functools.partial(_sample_row, temperature=temperature,
+                            top_p=top_p)
+
+    def sampler(logits, rids, steps):
+        keys = jax.vmap(lambda r, s: jax.random.fold_in(
+            jax.random.fold_in(base_key, r), s))(rids, steps)
+        return jax.vmap(row)(logits, keys)
+
+    return sampler
+
+
 class Engine:
     """The serving model runner: prefill + decode + keyed sampling.
 
@@ -430,23 +446,24 @@ class Engine:
                 and not cfg.sliding_window and not cfg.frontend
                 and not cfg.is_moe)
 
+    @property
+    def sample_knobs(self):
+        """The (temperature, top_p) that every traced sampler bakes in."""
+        return (self.temperature, self.top_p)
+
+    def sampler_fn(self):
+        """:func:`keyed_sampler` at this engine's key and current knobs.
+        :meth:`sample` jits it alone and the paged decode program traces
+        it inline, so both draw the same tokens from the same logits;
+        whatever traces it is rebuilt when :attr:`sample_knobs` change."""
+        return keyed_sampler(self._base_key, *self.sample_knobs)
+
     def _get_sampler(self):
-        """Jitted sampler for the CURRENT (temperature, top_p) — the
-        knobs steer python-level branches, so they are baked into the
-        trace; mutating them rebuilds the sampler (one retrace)."""
-        knobs = (self.temperature, self.top_p)
-        if knobs != self._sampler_knobs:
-            base_key, (temperature, top_p) = self._base_key, knobs
-
-            def sampler(logits, rids, steps):
-                keys = jax.vmap(lambda r, s: jax.random.fold_in(
-                    jax.random.fold_in(base_key, r), s))(rids, steps)
-                row = functools.partial(_sample_row, temperature=temperature,
-                                        top_p=top_p)
-                return jax.vmap(row)(logits, keys)
-
-            self._sampler = jax.jit(sampler)
-            self._sampler_knobs = knobs
+        """Jitted :meth:`sampler_fn` for the CURRENT knobs; mutating them
+        rebuilds it (one retrace)."""
+        if self.sample_knobs != self._sampler_knobs:
+            self._sampler = jax.jit(self.sampler_fn())
+            self._sampler_knobs = self.sample_knobs
         return self._sampler
 
     def sample(self, logits: jax.Array, rids, steps) -> jax.Array:

@@ -22,13 +22,15 @@ layers gathers each layer's dense view through the block tables
 (``max_len % block_size == 0`` makes its width the contiguous cache's),
 attends over it with each slot's new row merged in — the values and
 shapes the contiguous ``decode_step`` attends over, through the same
-layer body — and writes only the new rows into the pool, in place.  No
-whole-cache view outlives a layer.  Junk rows gathered from recycled
-blocks sit beyond every sequence's valid length, where the decode
-validity mask zeroes them exactly as it zeroes the contiguous cache's
-stale rows.  Preemption frees the victim's blocks; resume re-pins
-(prefix blocks re-shared, the rest freshly allocated).  The contiguous
-path stays the default — parity is testable request-for-request
+layer body — writes only the new rows into the pool, in place, and
+samples each slot's next token inside the program, so a pure decode
+step is one upload, one dispatch and one pull.  No whole-cache view
+outlives a layer.  Junk rows gathered from recycled blocks sit beyond
+every sequence's valid length, where the decode validity mask zeroes
+them exactly as it zeroes the contiguous cache's stale rows.
+Preemption frees the victim's blocks; resume re-pins (prefix blocks
+re-shared, the rest freshly allocated).  The contiguous path stays the
+default — parity is testable request-for-request
 (``tests/test_properties.py``).
 
 Admission (the v2 overhaul) no longer prefills one request per exact
@@ -53,7 +55,8 @@ prompt length:
     stream is bit-identical to an uninterrupted run.
 
 Sampling is keyed by (engine seed, request id, step) via
-``Engine.sample``, and all three admission paths share the engine's
+``Engine.sample`` (the paged decode program traces the same
+``Engine.sampler_fn``), and all three admission paths share the engine's
 canonical prefill recipe — a request's token sequence is bit-identical
 to serial ``Engine.generate_ids`` whether it was admitted alone, inside
 a bucket batch, in chunks, or after an eviction (enforced by test).
@@ -84,6 +87,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.profiler import TraceAnnotation
 
 from ..core.events import EngineStepped
@@ -91,8 +95,8 @@ from ..models.model import (copy_block, gather_cache, init_cache,
                             init_paged_cache, paged_decode_rows,
                             scatter_cache, scatter_decode_rows,
                             supports_paged_cache)
-from .engine import (Engine, GenerationResult, PrefillJob, cache_leaf_name,
-                     prefill_bucket)
+from .engine import (Engine, GenerationResult, PrefillJob, _named,
+                     cache_leaf_name, prefill_bucket)
 from .paging import BlockAllocator, PrefixCache
 
 
@@ -114,8 +118,9 @@ class Request:
     admission (-1 until then) and ``reused`` the prompt rows that
     shared prefix-cache blocks served there (0 off the paged path).  A
     resume after preemption keeps all three.  ``benchmarks/serving.py``
-    reads the TTFT stamps; ``bench/tools/program_metrics.py`` all of
-    them.
+    reads the TTFT stamps; the chip benchmark's ``bench/harness/serve.py``
+    and ``bench/metrics/queue_wait_p90_ms.py`` read them too, in the
+    per-layer metrics of ``bench/run.py --trace 1``.
     """
     rid: int
     prompt_ids: List[int]
@@ -164,6 +169,30 @@ def take_slot(batched_cache, slot):
         axis = big.ndim - _ROW_AXIS_OFFSET[cache_leaf_name(path)]
         return jax.lax.dynamic_slice_in_dim(big, slot, 1, axis)
     return jax.tree_util.tree_map_with_path(take, batched_cache)
+
+
+def paged_decode_step(params, pool, tables, step_in, *, cfg, trash,
+                      sampler, scatter=scatter_decode_rows):
+    """One sampled decode step of the slot batch against the donated
+    pool; returns ``(next tokens (n_slots,) int32, pool)``.
+
+    ``step_in`` is (5, n_slots) int32, packed so that the host uploads
+    one array: each slot's last token, position, request id, output step
+    and live flag.  Attention runs over per-layer views through
+    ``tables``; each live slot's new row is written in place, and a dead
+    or chunk-reserved slot's row lands in the ``trash`` block (its table
+    may already hold SHARED prefix blocks), by ``scatter``.  ``sampler``
+    is ``Engine.sampler_fn()``: the logits never leave the program."""
+    token, pos, rids, steps, live = step_in
+    wtables = jnp.where(live[:, None] != 0, tables, trash)
+    logits, rows = paged_decode_rows(params, cfg, pool, tables,
+                                     token[:, None], pos)
+    # the sampler reads the logits as stored in their dtype, unfused
+    # into the head, as it reads them after a separate decode program:
+    # the paged path samples what Engine.sample samples, bit for bit
+    logits = jax.lax.optimization_barrier(logits)
+    return (sampler(logits, rids, steps),
+            scatter(pool, rows, wtables, pos))
 
 
 class BatchScheduler:
@@ -245,8 +274,8 @@ class BatchScheduler:
         self._next_rid = 0
         self._seq = 0
         self._steps = 0
-        self._pos = [0] * n_slots   # next decode position per slot
-        self._tok = [0] * n_slots   # last sampled token per slot
+        self._pos = np.zeros(n_slots, np.int32)  # next decode position
+        self._tok = np.zeros(n_slots, np.int32)  # last sampled token
         dtype = self.engine.params["embed"].dtype
         self._paged = bool(paged_kv)
         if self._paged:
@@ -288,24 +317,14 @@ class BatchScheduler:
             self._gather = jax.jit(gather_cache)
             # the pool's writers: prefill rows, CoW block copies, and
             # decode's new rows — the last is traced into the decode
-            # program below when that first compiles
+            # program, which _decode_program builds at the first decode
+            # step for the engine's sampling knobs
             self._scatter_prefill = jax.jit(scatter_cache,
                                             donate_argnums=(0,))
             self._copy = jax.jit(copy_block, donate_argnums=(0,))
             self._scatter_rows = scatter_decode_rows
-            cfg = self.cfg
-
-            def paged_decode_step(params, pool, tables, wtables, token,
-                                  pos):
-                """One decode step of the slot batch against the donated
-                pool: attention over per-layer views, then each slot's
-                new row written in place (``wtables``: a dead slot's
-                row lands in the trash block)."""
-                logits, rows = paged_decode_rows(params, cfg, pool, tables,
-                                                 token, pos)
-                return logits, self._scatter_rows(pool, rows, wtables, pos)
-            self._decode_paged = jax.jit(paged_decode_step,
-                                         donate_argnums=(1,))
+            self._decode_paged = None
+            self._decode_knobs = None
         else:
             self._prefix = None
             self._cache = init_cache(self.cfg, n_slots, self._cache_len,
@@ -447,14 +466,29 @@ class BatchScheduler:
                            jnp.int32)
 
     def _tables_device(self) -> jax.Array:
-        """The (n_slots, max_blocks) int32 block-table array, rebuilt
-        lazily after any host-side table change."""
+        """The (n_slots, max_blocks) int32 block-table array, trash-padded,
+        rebuilt on the host and uploaded in one transfer after any
+        host-side table change."""
         if self._tables_dirty:
-            self._tables_dev = jnp.asarray(
-                [b + [self._trash] * (self._mb - len(b))
-                 for b in self._blocks], jnp.int32)
+            tables = np.full((self.n_slots, self._mb), self._trash, np.int32)
+            for slot, blocks in enumerate(self._blocks):
+                tables[slot, :len(blocks)] = blocks
+            self._tables_dev = jax.device_put(tables)
             self._tables_dirty = False
         return self._tables_dev
+
+    def _decode_program(self):
+        """``jit_paged_decode_step`` for the engine's current sampling
+        knobs: they are baked into its trace, so changing them rebuilds
+        it, as it rebuilds ``Engine.sample``'s sampler."""
+        if self._decode_knobs != self.engine.sample_knobs:
+            self._decode_paged = jax.jit(
+                _named(paged_decode_step, cfg=self.cfg, trash=self._trash,
+                       sampler=self.engine.sampler_fn(),
+                       scatter=self._scatter_rows),
+                donate_argnums=(1,))
+            self._decode_knobs = self.engine.sample_knobs
+        return self._decode_paged
 
     def _alloc_block(self) -> Optional[int]:
         """One fresh block, evicting LRU prefix-cache entries on demand;
@@ -510,7 +544,7 @@ class BatchScheduler:
         next write lands past them), but if a layout change ever does,
         the shared block is copied rather than corrupted.  False = pool
         exhausted (the caller self-preempts the slot)."""
-        bi = self._pos[slot] // self.block_size
+        bi = int(self._pos[slot]) // self.block_size
         blocks = self._blocks[slot]
         while bi >= len(blocks):
             bid = self._alloc_block()
@@ -858,9 +892,11 @@ class BatchScheduler:
 
         Traced as ``BatchScheduler.step`` with the phases as child spans:
         ``preempt``, ``admit`` (one child per admission), ``ensure_blocks``
-        (paged), ``decode_inputs`` (host arrays), ``decode_dispatch``
-        (the decode program and the sampler), ``readback`` (the sampled
-        tokens' pull to the host) and ``bookkeeping``."""
+        (paged), ``decode_inputs`` (the step's inputs: on the paged path
+        one packed upload, plus the block tables after a change),
+        ``decode_dispatch`` (the decode program; on the paged path it
+        samples inside, elsewhere the sampler follows), ``readback`` (one
+        pull of the sampled tokens to the host) and ``bookkeeping``."""
         with TraceAnnotation("BatchScheduler.step", step=self._steps,
                              live=self.occupancy(),
                              queued=self.queue_depth()):
@@ -892,35 +928,33 @@ class BatchScheduler:
         toks: List[int] = []
         if live:
             with TraceAnnotation("BatchScheduler.decode_inputs"):
-                tokens = jnp.asarray([[t] for t in self._tok], jnp.int32)
-                pos = jnp.asarray(self._pos, jnp.int32)
-                rids = [r.rid if (r := self.slots[i]) is not None else 0
-                        for i in range(self.n_slots)]
-                steps = [len(r.out_ids) if (r := self.slots[i]) is not None
-                         else 0 for i in range(self.n_slots)]
+                # token, position, request id, output step, live flag;
+                # a dead or chunk-reserved slot decodes junk at a stale
+                # position (exactly like the contiguous path) and keys
+                # its sample by (0, 0)
+                step_in = np.zeros((5, self.n_slots), np.int32)
+                step_in[0], step_in[1] = self._tok, self._pos
+                for i in live:
+                    step_in[2:, i] = (self.slots[i].rid,
+                                      len(self.slots[i].out_ids), 1)
                 if self._paged:
-                    # only LIVE slots write back: a dead or chunk-reserved
-                    # slot decodes junk at a stale position (exactly like
-                    # the contiguous path), and its table may already hold
-                    # SHARED prefix blocks — its row goes to the trash block
                     tables = self._tables_device()
-                    live_rows = jnp.asarray(
-                        [self.slots[i] is not None
-                         for i in range(self.n_slots)])
-                    wtables = jnp.where(live_rows[:, None], tables,
-                                        self._trash)
+                    step_dev = jax.device_put(step_in)
+                else:
+                    tokens = jnp.asarray(step_in[0, :, None])
+                    pos = jnp.asarray(step_in[1])
             with TraceAnnotation("BatchScheduler.decode_dispatch"):
                 if self._paged:
-                    logits, self._pool = self._decode_paged(
-                        self.engine.params, self._pool, tables, wtables,
-                        tokens, pos)
+                    sampled, self._pool = self._decode_program()(
+                        self.engine.params, self._pool, tables, step_dev)
                 else:
                     logits, self._cache = self.engine._decode(
                         self.engine.params, cache=self._cache, token=tokens,
                         pos=pos)
-                sampled = self.engine.sample(logits, rids, steps)
+                    sampled = self.engine.sample(logits, step_in[2],
+                                                 step_in[3])
             with TraceAnnotation("BatchScheduler.readback"):
-                toks = [int(t) for t in sampled]
+                toks = np.asarray(sampled).tolist()
         with TraceAnnotation("BatchScheduler.bookkeeping"):
             eos = self.engine.tokenizer.eos
             for i in live:

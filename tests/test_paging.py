@@ -291,9 +291,11 @@ def test_paged_decode_matches_decode_step_on_the_gathered_view(arch):
         assert jnp.array_equal(trash_rows, want[:, 2, MAX_LEN - 1])
 
 
-def _decoding_paged(n_req: int = 3) -> BatchScheduler:
-    """A paged scheduler whose next step is a pure decode step."""
-    sched = BatchScheduler(get_engine("gqa", 0.0), n_slots=3,
+def _decoding_paged(n_req: int = 3, arch: str = "gqa",
+                    temperature: float = 0.0) -> BatchScheduler:
+    """A paged scheduler over 3 slots whose next step is a pure decode
+    step of ``n_req`` live requests."""
+    sched = BatchScheduler(get_engine(arch, temperature), n_slots=3,
                            max_len=MAX_LEN, paged_kv=True, block_size=BLOCK)
     for i in range(n_req):
         sched.submit(prompt_ids=list(range(1 + i, 12 + 3 * i)), max_new=8)
@@ -302,38 +304,96 @@ def _decoding_paged(n_req: int = 3) -> BatchScheduler:
     return sched
 
 
-def test_paged_decode_step_is_one_program_and_donates_the_pool():
-    """A paged decode step dispatches one program, jit_paged_decode_step,
-    and nothing else of the engine's or the pool's; the program aliases
-    its pool input to its output and the donated pool is gone after."""
+def test_paged_decode_step_is_one_program_and_donates_the_pool(monkeypatch):
+    """A pure paged decode step makes one upload (the packed step
+    inputs; the block tables join it only in a step where a slot grew a
+    block), dispatches one program, jit_paged_decode_step, which samples
+    inside it (no sampler program, nothing else of the engine's or the
+    pool's), and makes one pull: the sampled tokens, (n_slots,) int32.
+    The program aliases its pool input to its output and the donated
+    pool is gone after."""
     sched = _decoding_paged()
     eng = sched.engine
     program = sched._decode_paged
-    calls = []
+    calls, uploads, pulls = [], [], []
+
+    class Tokens:
+        """The program's tokens, readable only as one whole array."""
+
+        def __init__(self, tokens):
+            self.tokens = tokens
+
+        def __array__(self, dtype=None, copy=None):
+            pulls.append((self.tokens.shape, self.tokens.dtype))
+            return np.asarray(self.tokens, dtype)
 
     def spy(name, fn):
         def call(*args, **kwargs):
             calls.append((name, args))
-            return fn(*args, **kwargs)
+            out = fn(*args, **kwargs)
+            return (Tokens(out[0]), out[1]) if name == "_decode_paged" else out
         return call
 
     for name in ("_gather", "_scatter_prefill", "_copy", "_decode_paged"):
-        setattr(sched, name, spy(name, getattr(sched, name)))
-    decode = eng._decode
-    eng._decode = spy("engine._decode", decode)
-    pool = sched._pool
-    try:
-        sched.step()
-    finally:
-        eng._decode = decode
-    assert [name for name, _ in calls] == ["_decode_paged"]
-    assert all(leaf.is_deleted() for leaf in jax.tree_util.tree_leaves(pool))
+        monkeypatch.setattr(sched, name, spy(name, getattr(sched, name)))
+    monkeypatch.setattr(eng, "_decode", spy("engine._decode", eng._decode))
+    monkeypatch.setattr(eng, "_sampler",
+                        spy("sampler", eng._get_sampler()))
+    put = jax.device_put
+
+    def device_put(x, *args, **kwargs):
+        uploads.append((np.shape(x), np.asarray(x).dtype))
+        return put(x, *args, **kwargs)
+
+    # the first step's write position opens slot 2's third block
+    tables = ((3, MAX_LEN // BLOCK), np.int32)
+    for want_uploads in ([tables, ((5, 3), np.int32)], [((5, 3), np.int32)]):
+        calls.clear(), uploads.clear(), pulls.clear()
+        pool = sched._pool
+        with monkeypatch.context() as m:
+            m.setattr(jax, "device_put", device_put)
+            sched.step()
+        assert [name for name, _ in calls] == ["_decode_paged"]
+        assert uploads == want_uploads
+        assert pulls == [((3,), jnp.int32)]
+        assert all(leaf.is_deleted()
+                   for leaf in jax.tree_util.tree_leaves(pool))
     shapes = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), calls[0][1])
     lowered = program.lower(*shapes)
     assert lowered.as_text().splitlines()[0].startswith(
         "module @jit_paged_decode_step ")
     assert "input_output_alias" in lowered.compile().as_text()
+
+
+@pytest.mark.parametrize("arch", ["gqa", "mla"])
+def test_paged_decode_samples_as_engine_sample(arch):
+    """The tokens a paged decode step serves are Engine.sample's draw
+    from the same logits, request ids and output steps: at temperature
+    1, and again after each change of the sampling knobs (the program is
+    rebuilt for them), two steps each.  One slot of three is dead, so
+    its row goes to the trash block and its sample is keyed (0, 0)."""
+    sched = _decoding_paged(n_req=2, arch=arch, temperature=1.0)
+    eng = sched.engine
+    logits_of = jax.jit(functools.partial(paged_decode_rows, cfg=eng.cfg))
+    knobs = eng.sample_knobs
+    try:
+        for eng.temperature, eng.top_p in (knobs, knobs, (0.7, 0.9),
+                                           (0.7, 0.9), (0.0, 1.0),
+                                           (0.0, 1.0)):
+            slots = [i for i, r in enumerate(sched.slots) if r is not None]
+            live = [sched.slots[i] for i in slots]
+            logits, _ = logits_of(eng.params, pool=sched._pool,
+                                  tables=sched._tables_device(),
+                                  token=jnp.asarray(sched._tok[:, None]),
+                                  pos=jnp.asarray(sched._pos))
+            want = eng.sample(logits[jnp.asarray(slots)],
+                              [r.rid for r in live],
+                              [len(r.out_ids) for r in live])
+            sched.step()
+            assert [r.out_ids[-1] for r in live] == np.asarray(want).tolist()
+    finally:
+        eng.temperature, eng.top_p = knobs
 
 
 # ---------------------------------------------------------------------------

@@ -21,9 +21,10 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.kernels import ops
-from repro.models.model import (decode_step, init_cache, init_paged_cache,
-                                paged_decode_rows, scatter_decode_rows)
+from repro.models.model import decode_step, init_cache, init_paged_cache
 from repro.models.params import init_params
+from repro.serving.engine import keyed_sampler
+from repro.serving.scheduler import paged_decode_step
 
 
 @pytest.fixture(scope="module")
@@ -121,10 +122,10 @@ def test_decode_step_compiles_for_v5e(spec, one_chip):
 
 def test_paged_decode_writes_the_pool_in_place_on_v5e(spec, one_chip):
     """qwen1.5-4b's widths (MHA, 20 x 128) cut to 4 layers, 8 slots x
-    2048 in blocks of 32: the paged decode program aliases its pool to
-    its output, copies no pool leaf, slices out no layer of the pool and
-    never holds a whole leaf's worth of view — only per-layer
-    temporaries."""
+    2048 in blocks of 32: the paged decode program, sampler inside,
+    aliases its pool to its output, copies no pool leaf, slices out no
+    layer of the pool and never holds a whole leaf's worth of view —
+    only per-layer temporaries."""
     cfg = dataclasses.replace(get_config("qwen1.5-4b"), n_layers=4)
     slots, blocks = 8, 2048 // 32
 
@@ -138,15 +139,12 @@ def test_paged_decode_writes_the_pool_in_place_on_v5e(spec, one_chip):
     pool = on_chip(jax.eval_shape(lambda: init_paged_cache(
         cfg, slots * blocks + 1, 32, dtype=jnp.bfloat16)))
 
-    def paged_decode_step(params, pool, tables, wtables, token, pos):
-        logits, rows = paged_decode_rows(params, cfg, pool, tables, token,
-                                         pos)
-        return logits, scatter_decode_rows(pool, rows, wtables, pos)
-
-    tables = spec((slots, blocks), jnp.int32)
-    compiled = jax.jit(paged_decode_step, donate_argnums=(1,)).lower(
-        params, pool, tables, tables, spec((slots, 1), jnp.int32),
-        spec((slots,), jnp.int32)).compile()
+    step = functools.partial(
+        paged_decode_step, cfg=cfg, trash=slots * blocks,
+        sampler=keyed_sampler(jax.random.key(1), 1.0, 1.0))
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, pool, spec((slots, blocks), jnp.int32),
+        spec((5, slots), jnp.int32)).compile()
     hlo = compiled.as_text()
     leaf = pool["layers"]["k"]
     leaf_bytes = leaf.size * leaf.dtype.itemsize
